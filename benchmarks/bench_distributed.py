@@ -1,7 +1,7 @@
 """Mesh-sharded batched execution (``resources.distributed = "data"``).
 
-Measures, per shard count K ∈ {1, 2, 4, 8} on a forced 8-device host
-platform (``--xla_force_host_platform_device_count=8``):
+Measures, in process, per shard count K (the powers of two up to the
+number of devices present):
 
 * round wall time with the stacked client dimension sharded K ways
   (params replicated, client data / local states sharded) vs the
@@ -10,30 +10,18 @@ platform (``--xla_force_host_platform_device_count=8``):
   saves per device;
 * sharded FedAvg aggregation (per-shard partials + psum epilogue) time.
 
-Host-platform devices share the same CPU cores, so this benchmark proves
-the *mechanism* and reports per-shard round times; real speedups need
-real accelerators and are not gated by ``scripts/check_bench.py``.
-
-Run standalone (owns the XLA flag) or via ``benchmarks.run`` (spawns a
-subprocess because jax is already initialized there):
+It starts no child process: a chip belongs to one process, so a child
+of a process that already holds the devices could not reach them.  On a
+single-device host only K = 1 runs; the multi-device mechanism is
+rehearsed on forced host devices by ``tests/test_distributed_batched.py``.
 
     PYTHONPATH=src python -m benchmarks.bench_distributed
 """
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
-
-_FLAG = "--xla_force_host_platform_device_count=8"
-
-if "--worker" in sys.argv:
-    os.environ["XLA_FLAGS"] = _FLAG
-
 import time
-from typing import Dict, Iterable
+from typing import Dict, Iterable, Optional
 
-KS = (1, 2, 4, 8)
 N_CLIENTS = 64
 
 
@@ -60,13 +48,19 @@ def _make_trainer(n: int, devices=None):
     fed = build_federated_data(cfg.data)
     trainer = Trainer(cfg, model, fed, server=Server(model, cfg, fed.test))
     if devices:
-        trainer.engine = BatchedExecutor(model, distributed="data",
+        trainer.engine = BatchedExecutor(model, max_clients=n, distributed="data",
                                          devices=devices)
     trainer.server.params = model.init(jax.random.PRNGKey(cfg.seed))
     return trainer
 
 
-def collect(ks: Iterable[int] = KS, n: int = N_CLIENTS) -> Dict[str, Dict]:
+def _shard_counts():
+    import jax
+    return tuple(1 << i for i in range(len(jax.devices()).bit_length()))
+
+
+def collect(ks: Optional[Iterable[int]] = None,
+            n: int = N_CLIENTS) -> Dict[str, Dict]:
     import jax
     import numpy as np
 
@@ -85,7 +79,7 @@ def collect(ks: Iterable[int] = KS, n: int = N_CLIENTS) -> Dict[str, Dict]:
     rng = np.random.RandomState(0)
     u = rng.randn(n, 50_000).astype(np.float32)
     w = (np.ones(n) / n).astype(np.float32)
-    for k in ks:
+    for k in ks or _shard_counts():
         if k > len(jax.devices()):
             continue
         trainer = _make_trainer(n, devices=jax.devices()[:k])
@@ -104,7 +98,7 @@ def collect(ks: Iterable[int] = KS, n: int = N_CLIENTS) -> Dict[str, Dict]:
     return out
 
 
-def _worker() -> None:
+def main() -> None:
     from benchmarks.common import emit
 
     data = collect()
@@ -112,36 +106,14 @@ def _worker() -> None:
     base = data["round_s"]["batched"]
     rows.append((f"dist_roundtime_s_batched_N{N_CLIENTS}", base,
                  f"{data['clients_per_shard']['batched']} clients/device"))
-    for k in KS:
-        key = str(k)
-        if key not in data["round_s"]:
-            continue
-        rows.append((f"dist_roundtime_s_mesh{k}_N{N_CLIENTS}",
+    for key in data["agg_sharded_s"]:
+        rows.append((f"dist_roundtime_s_mesh{key}_N{N_CLIENTS}",
                      data["round_s"][key],
                      f"{data['clients_per_shard'][key]} clients/shard"))
-        rows.append((f"dist_agg_psum_s_mesh{k}", data["agg_sharded_s"][key],
+        rows.append((f"dist_agg_psum_s_mesh{key}", data["agg_sharded_s"][key],
                      "per-shard partials + psum"))
     emit(rows)
 
 
-def main() -> None:
-    """Spawn the flag-owning worker (jax may already be initialized here)."""
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.path.join(root, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    r = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_distributed", "--worker"],
-        cwd=root, env=env, capture_output=True, text=True, timeout=1200)
-    sys.stdout.write(r.stdout)
-    if r.returncode != 0:
-        sys.stderr.write(r.stderr[-3000:])
-        raise RuntimeError("bench_distributed worker failed")
-
-
 if __name__ == "__main__":
-    if "--worker" in sys.argv:
-        _worker()
-    else:
-        main()
+    main()

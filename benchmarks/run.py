@@ -67,6 +67,8 @@ def main() -> None:
     ap.add_argument("--json", default="", metavar="BENCH_roundtime.json",
                     help="write round-time regression numbers to PATH and exit")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.json:
         run_json(args.json)
         return

@@ -277,7 +277,7 @@ def check_contracts(baseline_path: Optional[str] = None,
     agg_w = jnp.asarray(
         (np.ones(16) / 16).astype(np.float32))
     tree_args = (agg_u, agg_w)
-    tree_kw = dict(fanout=4, use_kernel=True, interpret=True,
+    tree_kw = dict(fanout=4, use_kernel=True, interpret=None,
                    tile_d=fedavg_agg.TILE_D, tile_n=fedavg_agg.TILE_N)
     fedavg_agg._tree_padded.clear_cache()
     tt0 = fedavg_agg.tree_trace_count()
@@ -369,7 +369,7 @@ def check_contracts(baseline_path: Optional[str] = None,
                                  ClientConfig(lr=0.1, local_epochs=1),
                                  batch_size=BATCH))
     from repro.core.batched import BatchedExecutor
-    executor = BatchedExecutor(model)
+    executor = BatchedExecutor(model, max_clients=N_CLIENTS)
     executor.run_round_fused(ex_clients, model.init(jax.random.PRNGKey(0)),
                              round_id=0)        # warm-up (compile round)
     d0, h0 = batched.dispatch_count(), batched.host_sync_count()

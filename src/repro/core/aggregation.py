@@ -55,13 +55,13 @@ def weighted_average(updates: List[PyTree], weights: np.ndarray,
         if topology == "hierarchical":
             from repro.kernels.fedavg_agg import fedavg_aggregate_tree
             return unravel(fedavg_aggregate_tree(
-                stacked, weights, fanout=fanout, use_kernel=use_kernel,
-                interpret=kops.get_interpret(None) if use_kernel else True))
+                stacked, weights, fanout=fanout, use_kernel=use_kernel))
         return unravel(kops.fedavg_aggregate(stacked, weights))
 
     def avg(*leaves):
         stacked = jnp.stack([l.astype(jnp.float32) for l in leaves])
-        return jnp.einsum("n,n...->...", weights, stacked)
+        return jnp.einsum("n,n...->...", weights, stacked,
+                          precision=jax.lax.Precision.HIGHEST)
 
     return jax.tree_util.tree_map(avg, *updates)
 

@@ -109,6 +109,9 @@ from repro.optim import (
 PyTree = Any
 
 CLIENT_AXIS = "clients"
+# FedAvg's weighted sums stay exact f32 on every backend (the TPU's default
+# f32 matmul rounds its operands to bf16)
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 class CohortVectors(NamedTuple):
@@ -331,6 +334,109 @@ def make_cohort_program(model: FLModel, optimizer: TracedOptimizer,
                    donate_argnums=(0,))
 
 
+def _server_step_fn(method: str, stc_sparsity: float, use_faults: bool,
+                    max_update_norm: float, topology: str, fanout: int,
+                    use_kernel: bool, server_lr: float,
+                    interpret: Optional[bool], mesh):
+    """Server half of the fused round program (:func:`make_round_program`):
+    compression with the error-feedback residual update, the fault guard,
+    FedAvg and the apply, on stacked client updates.
+
+        (global_params, updates, weights, mask, nan_mask, ef_leaves,
+         ef_rows) -> (new_global_params, guard_ok, nnz, new_ef_leaves)
+
+    Op for op the staged ``compress_stacked`` + ``aggregate_stacked`` +
+    ``aggregation.apply_delta``; kept apart from the training so the two
+    paths can be compared on the same updates."""
+    tree = topology == "hierarchical"
+
+    def server_step(global_params, updates, weights, mask, nan_mask,
+                    ef_leaves, ef_rows):
+        from repro.core.compression import DENSE_MIN_ELEMS
+        from repro.core.tiered_store import set_rows
+        from repro.kernels import ops as kops
+        from repro.kernels.fedavg_agg import (fedavg_aggregate_sharded,
+                                              fedavg_aggregate_tree)
+
+        leaves, treedef = jax.tree_util.tree_flatten(updates)
+        nb = leaves[0].shape[0]
+        flat_leaves, nnz_list, new_ef = [], [], []
+        for li, leaf in enumerate(leaves):
+            size = int(np.prod(leaf.shape[1:], dtype=np.int64))
+            flat = leaf.reshape(nb, size).astype(jnp.float32)
+            if method != "none":
+                # error-correct by the stored residual; padded clients
+                # (row sentinel = alloc) gather 0 / write nowhere, so
+                # semantics match the staged compress_stacked exactly
+                res = jnp.take(ef_leaves[li], ef_rows, axis=0,
+                               mode="fill", fill_value=0.0)
+                corrected = flat + res
+                if size < DENSE_MIN_ELEMS:   # tiny tensors stay dense
+                    sent = corrected
+                elif method == "stc":
+                    sent, nnz = kops.stc_compress_batched(
+                        corrected, stc_sparsity, interpret=interpret,
+                        mesh=mesh)
+                    nnz_list.append(nnz)
+                else:
+                    sent, _ = kops.int8_roundtrip_batched(
+                        corrected, interpret=interpret, mesh=mesh)
+                new_ef.append(set_rows(ef_leaves[li], ef_rows,
+                                       corrected - sent))
+                flat = sent
+            flat_leaves.append(flat)
+        flat = (flat_leaves[0] if len(flat_leaves) == 1
+                else jnp.concatenate(flat_leaves, axis=1))
+
+        if use_faults:
+            # identical op order to aggregate_stacked's fault branch:
+            # poison AFTER compression, guard on the sent values, zero
+            # rejected rows in the data, renormalize the survivors
+            flat = jnp.where(nan_mask[:, None], jnp.float32(jnp.nan), flat)
+            wj = weights * mask
+            ok = jnp.isfinite(flat).all(axis=1)
+            if max_update_norm > 0:
+                norms = jnp.sqrt(jnp.sum(
+                    jnp.square(flat.astype(jnp.float32)), axis=1))
+                ok = ok & (norms <= max_update_norm)
+            wj = wj * ok.astype(jnp.float32)
+            flat = jnp.where(ok[:, None], flat, 0.0)
+            wsum = jnp.sum(wj)
+            w = jnp.where(wsum > 0, wj / wsum, 0.0)
+        else:
+            ok = jnp.ones((nb,), bool)
+            w = weights
+
+        if mesh is not None:
+            delta = fedavg_aggregate_sharded(
+                flat, w, mesh, interpret=interpret,
+                fanout=(fanout or int(np.ceil(np.sqrt(nb)))) if tree else 0)
+        elif tree:
+            delta = fedavg_aggregate_tree(
+                flat, w, fanout=fanout, use_kernel=use_kernel,
+                interpret=interpret)
+        elif use_kernel:
+            delta = kops.fedavg_aggregate(flat, w, interpret=interpret)
+        else:
+            delta = jnp.einsum("n,nd->d", w, flat.astype(jnp.float32),
+                               precision=HIGHEST)
+
+        out, off = [], 0
+        for leaf in leaves:
+            size = int(np.prod(leaf.shape[1:], dtype=np.int64))
+            out.append(delta[off: off + size].reshape(leaf.shape[1:]))
+            off += size
+        delta_tree = jax.tree_util.tree_unflatten(treedef, out)
+        # the server apply (aggregation.apply_delta), in-program
+        new_global = jax.tree_util.tree_map(
+            lambda p, d: (p.astype(jnp.float32)
+                          + server_lr * d).astype(p.dtype),
+            global_params, delta_tree)
+        return new_global, ok, tuple(nnz_list), tuple(new_ef)
+
+    return server_step
+
+
 @lru_cache(maxsize=16)
 def make_round_program(model: FLModel, optimizer: TracedOptimizer,
                        steps: int, use_prox: bool, use_clip: bool,
@@ -338,7 +444,8 @@ def make_round_program(model: FLModel, optimizer: TracedOptimizer,
                        use_faults: bool = False,
                        max_update_norm: float = 0.0, topology: str = "flat",
                        fanout: int = 0, use_kernel: bool = False,
-                       server_lr: float = 1.0, interpret: bool = True,
+                       server_lr: float = 1.0,
+                       interpret: Optional[bool] = None,
                        mesh=None):
     """ONE jitted program for the whole round (``resources.round_fusion``).
 
@@ -378,17 +485,14 @@ def make_round_program(model: FLModel, optimizer: TracedOptimizer,
     """
     one_client = _one_client_fn(model, optimizer, steps, use_prox, use_clip)
     batched = jax.vmap(one_client, in_axes=(0, 0, 0, 0, 0, 0, None))
-    tree = topology == "hierarchical"
+    server_step = _server_step_fn(method, stc_sparsity, use_faults,
+                                  max_update_norm, topology, fanout,
+                                  use_kernel, server_lr, interpret, mesh)
 
     def round_fn(global_params, x, y, idx, n_steps, vec, weights, mask,
                  nan_mask, ef_leaves, ef_rows):
         global _round_traces
         _round_traces += 1           # executes once per jit trace/compile
-        from repro.core.compression import DENSE_MIN_ELEMS
-        from repro.kernels import ops as kops
-        from repro.kernels.fedavg_agg import (fedavg_aggregate_sharded,
-                                              fedavg_aggregate_tree)
-
         nb = x.shape[0]
         stacked = jax.tree_util.tree_map(
             lambda p: jnp.broadcast_to(p[None], (nb,) + p.shape),
@@ -399,80 +503,10 @@ def make_round_program(model: FLModel, optimizer: TracedOptimizer,
                 stacked, NamedSharding(mesh, P(CLIENT_AXIS)))
         updates, loss, acc = batched(stacked, x, y, idx, n_steps, vec,
                                      global_params)
-
-        leaves, treedef = jax.tree_util.tree_flatten(updates)
-        flat_leaves, nnz_list, new_ef = [], [], []
-        for li, leaf in enumerate(leaves):
-            size = int(np.prod(leaf.shape[1:], dtype=np.int64))
-            flat = leaf.reshape(nb, size).astype(jnp.float32)
-            if method != "none":
-                # error-correct by the stored residual; padded clients
-                # (row sentinel = alloc) gather 0 / scatter nowhere, so
-                # semantics match the staged compress_stacked exactly
-                res = jnp.take(ef_leaves[li], ef_rows, axis=0,
-                               mode="fill", fill_value=0.0)
-                corrected = flat + res
-                if size < DENSE_MIN_ELEMS:   # tiny tensors stay dense
-                    sent = corrected
-                elif method == "stc":
-                    sent, nnz = kops.stc_compress_batched(
-                        corrected, stc_sparsity, interpret=interpret,
-                        mesh=mesh)
-                    nnz_list.append(nnz)
-                else:
-                    sent, _ = kops.int8_roundtrip_batched(
-                        corrected, interpret=interpret, mesh=mesh)
-                new_ef.append(ef_leaves[li].at[ef_rows].set(
-                    corrected - sent, mode="drop"))
-                flat = sent
-            flat_leaves.append(flat)
-        flat = (flat_leaves[0] if len(flat_leaves) == 1
-                else jnp.concatenate(flat_leaves, axis=1))
-
-        if use_faults:
-            # identical op order to aggregate_stacked's fault branch:
-            # poison AFTER compression, guard on the sent values, zero
-            # rejected rows in the data, renormalize the survivors
-            flat = jnp.where(nan_mask[:, None], jnp.float32(jnp.nan), flat)
-            wj = weights * mask
-            ok = jnp.isfinite(flat).all(axis=1)
-            if max_update_norm > 0:
-                norms = jnp.sqrt(jnp.sum(
-                    jnp.square(flat.astype(jnp.float32)), axis=1))
-                ok = ok & (norms <= max_update_norm)
-            wj = wj * ok.astype(jnp.float32)
-            flat = jnp.where(ok[:, None], flat, 0.0)
-            wsum = jnp.sum(wj)
-            w = jnp.where(wsum > 0, wj / wsum, 0.0)
-        else:
-            ok = jnp.ones((nb,), bool)
-            w = weights
-
-        if mesh is not None:
-            delta = fedavg_aggregate_sharded(
-                flat, w, mesh, interpret=interpret,
-                fanout=(fanout or int(np.ceil(np.sqrt(nb)))) if tree else 0)
-        elif tree:
-            delta = fedavg_aggregate_tree(
-                flat, w, fanout=fanout, use_kernel=use_kernel,
-                interpret=interpret if use_kernel else True)
-        elif use_kernel:
-            delta = kops.fedavg_aggregate(flat, w, interpret=interpret)
-        else:
-            delta = jnp.einsum("n,nd->d", w, flat.astype(jnp.float32))
-
-        out, off = [], 0
-        for leaf in leaves:
-            size = int(np.prod(leaf.shape[1:], dtype=np.int64))
-            out.append(delta[off: off + size].reshape(leaf.shape[1:]))
-            off += size
-        delta_tree = jax.tree_util.tree_unflatten(treedef, out)
-        # the server apply (aggregation.apply_delta), in-program
-        new_global = jax.tree_util.tree_map(
-            lambda p, d: (p.astype(jnp.float32)
-                          + server_lr * d).astype(p.dtype),
-            global_params, delta_tree)
-        return (new_global, loss, acc, ok, tuple(nnz_list), tuple(new_ef))
+        new_global, ok, nnz, new_ef = server_step(
+            global_params, updates, weights, mask, nan_mask, ef_leaves,
+            ef_rows)
+        return (new_global, loss, acc, ok, nnz, new_ef)
 
     if mesh is None:
         return jax.jit(round_fn, donate_argnums=(0, 9))
@@ -497,7 +531,10 @@ class BatchedExecutor:
     ``distributed="data"`` shards the stacked client dimension over a 1-D
     device mesh (see module docstring); ``devices`` overrides the device
     set (tests use prefixes of the host platform's forced devices to prove
-    shard-count invariance)."""
+    shard-count invariance).  ``max_clients`` is how many clients' data
+    and error-feedback rows stay on the device (further capped by the two
+    class bounds below); both tiers allocate that many rows on first use
+    and spill beyond it."""
 
     #: bound on the *device-resident* tier of the per-client data pool
     #: (rows).  Cold clients beyond the bound are LRU-evicted and cost
@@ -511,7 +548,8 @@ class BatchedExecutor:
     #: reload bit-identically (residuals are state, not recomputable)
     EF_MAX_CLIENTS = 1024
 
-    def __init__(self, model: FLModel, distributed: str = "none",
+    def __init__(self, model: FLModel, max_clients: int,
+                 distributed: str = "none",
                  devices: Optional[Sequence] = None):
         if distributed not in ("none", "data"):
             raise ValueError(
@@ -534,8 +572,14 @@ class BatchedExecutor:
         # hot rows live in per-leaf (alloc, leaf_size) device matrices,
         # evicted rows spill to host and reload bit-identically, so
         # round-over-round semantics match ``Client._residual`` exactly —
-        # including across async waves, which share this executor
+        # including across async waves, which share this executor.
+        # Both stores hold at most ``max_clients`` hot rows, allocated at
+        # once, so the round program that takes the whole EF store never
+        # retraces as new clients arrive
         self._ef = None                # lazily-built TieredRowStore
+        if max_clients < 1:
+            raise ValueError(f"max_clients must be >= 1, got {max_clients}")
+        self.max_clients = int(max_clients)
 
     # ------------------------------------------------------------------
     @property
@@ -559,6 +603,12 @@ class BatchedExecutor:
         rows = [cyclic_batches(len(client.data), client._batch_size(), seed + e)
                 for e in range(client.cfg.local_epochs)]
         return np.concatenate(rows).astype(np.int32)
+
+    def _new_ef_store(self):
+        from repro.core.tiered_store import TieredRowStore
+
+        return TieredRowStore(min(self.EF_MAX_CLIENTS, self.max_clients),
+                              spill="host", mesh=self.mesh, name="ef-store")
 
     # ------------------------------------------------------------------
     def invalidate_data(self, client_id: Optional[str] = None) -> None:
@@ -606,8 +656,9 @@ class BatchedExecutor:
         if self._pool is not None and self._pool_sig != sig:
             self._pool = None          # dataset/shape changed: reset
         if self._pool is None:
-            self._pool = TieredRowStore(self.DATA_POOL_MAX_CLIENTS,
-                                        spill="drop", name="data-pool")
+            self._pool = TieredRowStore(
+                min(self.DATA_POOL_MAX_CLIENTS, self.max_clients),
+                spill="drop", name="data-pool")
             self._pool_sig = sig
             self._pool_maxn = maxn
         if maxn > self._pool_maxn:
@@ -863,11 +914,8 @@ class BatchedExecutor:
         sizes = [int(np.prod(l.shape, dtype=np.int64))
                  for l in jax.tree_util.tree_leaves(global_params)]
         if method != "none":
-            from repro.core.tiered_store import TieredRowStore
-
             if self._ef is None:
-                self._ef = TieredRowStore(self.EF_MAX_CLIENTS, spill="host",
-                                          mesh=self.mesh, name="ef-store")
+                self._ef = self._new_ef_store()
             if self._ef.leaves and \
                     [l.shape[1] for l in self._ef.leaves] != sizes:
                 raise ValueError(
@@ -894,7 +942,14 @@ class BatchedExecutor:
             use_faults=use_faults, max_update_norm=float(max_update_norm),
             topology=topology, fanout=int(fanout), use_kernel=use_kernel,
             server_lr=float(server_lr),
-            interpret=kops.get_interpret(interpret), mesh=self.mesh)
+            interpret=interpret, mesh=self.mesh)
+        if self.mesh is not None:
+            # the program returns params replicated over the mesh; place the
+            # first round's params the same way, or round 2 would retrace on
+            # the changed input sharding (a no-op once they are placed)
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            global_params = jax.device_put(global_params,
+                                           NamedSharding(self.mesh, P()))
 
         t0 = time.perf_counter()
         with warnings.catch_warnings():
@@ -984,12 +1039,9 @@ class BatchedExecutor:
         mesh the hot tier stays sharded along its row axis, so the
         round-trip gather/scatter never funnels residuals through one
         device."""
-        from repro.core.tiered_store import TieredRowStore
-
         sizes = [int(np.prod(l.shape[1:], dtype=np.int64)) for l in leaves]
         if self._ef is None:
-            self._ef = TieredRowStore(self.EF_MAX_CLIENTS, spill="host",
-                                      mesh=self.mesh, name="ef-store")
+            self._ef = self._new_ef_store()
         if self._ef.leaves and \
                 [l.shape[1] for l in self._ef.leaves] != sizes:
             raise ValueError(
@@ -1019,10 +1071,7 @@ class BatchedExecutor:
         """Restore :meth:`ef_state` into the warm tier (rows re-heat — and
         re-shard onto the client mesh — on their next gather).  Accepts
         the legacy dense ``{"rows", "store"}`` snapshot format too."""
-        from repro.core.tiered_store import TieredRowStore
-
-        self._ef = TieredRowStore(self.EF_MAX_CLIENTS, spill="host",
-                                  mesh=self.mesh, name="ef-store")
+        self._ef = self._new_ef_store()
         if "clients" in state:
             self._ef.load_state(state)
             return
@@ -1072,7 +1121,6 @@ class BatchedExecutor:
         nb = leaves[0].shape[0]
         n = len(clients)
         residuals, ids = self._ef_gather(clients, leaves)
-        itp = kops.get_interpret(interpret)
         sharding = None
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1091,10 +1139,11 @@ class BatchedExecutor:
                 sent, nnz = corrected, None
             elif method == "stc":
                 sent, nnz = kops.stc_compress_batched(
-                    corrected, stc_sparsity, interpret=itp, mesh=self.mesh)
+                    corrected, stc_sparsity, interpret=interpret,
+                    mesh=self.mesh)
             else:
                 sent, _ = kops.int8_roundtrip_batched(
-                    corrected, interpret=itp, mesh=self.mesh)
+                    corrected, interpret=interpret, mesh=self.mesh)
                 nnz = None
             new_res.append((corrected - sent)[:n])
             sent_leaves.append(sent.reshape(leaf.shape))
@@ -1221,19 +1270,18 @@ class BatchedExecutor:
         if self.mesh is not None:
             delta = fedavg_aggregate_sharded(
                 flat, jnp.asarray(w), self.mesh,
-                interpret=kops.get_interpret(interpret),
+                interpret=interpret,
                 fanout=(fanout or int(np.ceil(np.sqrt(nb)))) if tree else 0)
         elif tree:
             delta = fedavg_aggregate_tree(
                 flat, jnp.asarray(w), fanout=fanout, use_kernel=use_kernel,
-                interpret=kops.get_interpret(interpret) if use_kernel
-                else True)
+                interpret=interpret)
         elif use_kernel:
             delta = kops.fedavg_aggregate(flat, jnp.asarray(w),
                                           interpret=interpret)
         else:
             delta = jnp.einsum("n,nd->d", jnp.asarray(w),
-                               flat.astype(jnp.float32))
+                               flat.astype(jnp.float32), precision=HIGHEST)
         _note_dispatch()               # the staged aggregation stage
         # unravel by leaf shape (slices are views; no copy of the model)
         out, off = [], 0

@@ -148,7 +148,16 @@ class Trainer:
                 f"{config.server.clients_per_round} clients are selected "
                 f"per round")
         # async dispatch waves run through the batched executor too
-        self.engine = (BatchedExecutor(model, distributed=res.distributed)
+        # rows the engine keeps on the device: every client a synchronous
+        # run can touch (rounds x cohort), or the clients in flight under
+        # async waves (finished ones spill to the host)
+        cohort = config.server.clients_per_round
+        max_clients = (config.server.rounds * cohort
+                       if res.execution == "batched"
+                       else max(res.max_concurrency, cohort))
+        max_clients = max(1, min(max_clients, len(fed_data.client_ids)))
+        self.engine = (BatchedExecutor(model, distributed=res.distributed,
+                                       max_clients=max_clients)
                        if res.execution in ("batched", "async") else None)
         self.het = SystemHeterogeneity(config.system_heterogeneity)
         self.scheduler = GreedyAda(
@@ -485,9 +494,15 @@ class Trainer:
                     [i for i, c in enumerate(clients)
                      if plans[c.client_id].nan_update], np.int32)
                 if nan_rows.size:
-                    st["updates"] = jax.tree_util.tree_map(
-                        lambda a: a.at[nan_rows].set(jnp.nan),
-                        st["updates"])
+                    # a select, not a scatter: see tiered_store.set_rows
+                    def poison(a):
+                        hit = np.zeros((a.shape[0],) + (1,) * (a.ndim - 1),
+                                       bool)
+                        hit[nan_rows] = True
+                        return jnp.where(hit, jnp.nan, a)
+
+                    st["updates"] = jax.tree_util.tree_map(poison,
+                                                           st["updates"])
             delta = self.engine.aggregate_stacked(
                 st, use_kernel=self.cfg.resources.aggregation_kernel,
                 mask=mask, guard=plans is not None,

@@ -25,25 +25,39 @@ The device tier never evicts a row that the *current* cohort pins, so a
 cohort larger than ``capacity`` transparently grows the tier to the cohort
 size for that round (the documented device-memory bound is
 ``max(capacity, cohort)`` rows).  Row slots are recycled through a free
-list; allocation grows by power-of-two doubling so repeated growth does
-not re-copy quadratically.
+list.  The hot tier is allocated once, at ``capacity`` rows, on the first
+insert, so its shape stays fixed while the resident rows fit: the fused
+round program takes the error-feedback store's matrices whole, and each
+new shape would be a recompile.  Callers size ``capacity`` to the clients
+a run can touch.
 
 See ``docs/scale.md`` for the end-to-end walkthrough.
 """
 from __future__ import annotations
 
 from collections import OrderedDict
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Sequence
 
 import jax.numpy as jnp
 import numpy as np
 
 
-def _bucket(n: int, floor: int = 1) -> int:
-    b = max(1, floor)
-    while b < n:
-        b *= 2
-    return b
+def set_rows(matrix, rows, values):
+    """``matrix.at[rows].set(values, mode="drop")`` as a gather and a select.
+
+    Row ``r`` of the result is ``values[i]`` where ``rows[i] == r``, else
+    the old row; out-of-range entries of ``rows`` write nothing, and no
+    in-range entry may repeat.  XLA's partitioned scatter into a matrix
+    sharded along its rows wrote wrong rows on a TPU v5e 2x2 (JAX 0.9.0),
+    while the partitioned row gather is exact, so row writes go this way.
+    """
+    rows = jnp.asarray(rows)
+    match = rows[None, :] == jnp.arange(matrix.shape[0],
+                                        dtype=rows.dtype)[:, None]
+    picked = jnp.take(jnp.asarray(values, matrix.dtype),
+                      jnp.argmax(match, axis=1), axis=0)
+    hit = jnp.any(match, axis=1).reshape((-1,) + (1,) * (matrix.ndim - 1))
+    return jnp.where(hit, picked, matrix)
 
 
 class TieredRowStore:
@@ -111,9 +125,6 @@ class TieredRowStore:
         self._host = {}
 
     # ------------------------------------------------------------------
-    def _floor(self) -> int:
-        return max(8, self.mesh.size) if self.mesh is not None else 8
-
     def _place(self, leaves: List[Any]) -> List[Any]:
         if self.mesh is None or not leaves:
             return leaves
@@ -124,9 +135,9 @@ class TieredRowStore:
                                         *(None,) * (leaves[0].ndim - 1)))
         return [jax.device_put(m, sh) for m in leaves]
 
-    def _grow(self, need: int, cap_eff: int) -> None:
-        """Grow hot-tier allocation to hold ``need`` rows (<= cap_eff)."""
-        new_alloc = max(min(_bucket(need, self._floor()), cap_eff), need)
+    def _grow(self, cap_eff: int) -> None:
+        """Grow the hot-tier allocation to ``cap_eff`` rows."""
+        new_alloc = cap_eff
         if self.mesh is not None:
             m = self.mesh.size
             new_alloc = -(-new_alloc // m) * m
@@ -200,13 +211,13 @@ class TieredRowStore:
             if over > 0:
                 self._evict(over, pinned)
             if len(missing) > len(self._free):
-                self._grow(len(self.rows) + len(missing), cap_eff)
+                self._grow(cap_eff)
             slots = [self._free.pop() for _ in missing]
             stacked = [np.stack([v[li] for v in values])
                        for li in range(len(self.leaves))]
-            sl = jnp.asarray(np.asarray(slots))
+            sl = np.asarray(slots, np.int32)
             self.leaves = self._place([
-                leaf.at[sl].set(jnp.asarray(vals))
+                set_rows(leaf, sl, vals)
                 for leaf, vals in zip(self.leaves, stacked)])
             for cid, slot in zip(missing, slots):
                 self.rows[cid] = slot
@@ -230,9 +241,9 @@ class TieredRowStore:
         """Write per-leaf ``(len(ids), *shape)`` values back to hot rows.
 
         Ids must be resident (callers scatter right after a gather)."""
-        idx = jnp.asarray(np.asarray([self.rows[c] for c in ids], np.int32))
+        idx = np.asarray([self.rows[c] for c in ids], np.int32)
         self.leaves = self._place([
-            m.at[idx].set(vals) for m, vals in zip(self.leaves, leaves)])
+            set_rows(m, idx, vals) for m, vals in zip(self.leaves, leaves)])
 
     # ------------------------------------------------------------------
     def drop(self, cid: str) -> None:

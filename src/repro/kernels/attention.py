@@ -27,10 +27,13 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 TILE_Q = 64
 TILE_K = 64
@@ -38,17 +41,13 @@ NEG_INF = -1e30
 _TINY = 1e-30          # denominator floor for fully-masked (padded) rows
 
 
-def _iota(n):
-    return jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)[:, 0]
-
-
 def _tile_mask(i, j, tile_q, tile_k, s_real: int, causal: bool):
     """(tile_q, tile_k) bool validity mask from *global* row/col indices."""
-    qi = i * tile_q + _iota(tile_q)
-    kj = j * tile_k + _iota(tile_k)
-    mask = (qi[:, None] < s_real) & (kj[None, :] < s_real)
+    qi = i * tile_q + jax.lax.broadcasted_iota(jnp.int32, (tile_q, tile_k), 0)
+    kj = j * tile_k + jax.lax.broadcasted_iota(jnp.int32, (tile_q, tile_k), 1)
+    mask = (qi < s_real) & (kj < s_real)
     if causal:
-        mask &= kj[None, :] <= qi[:, None]
+        mask &= kj <= qi
     return mask
 
 
@@ -82,30 +81,33 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, lse_ref, *,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale    # (tq, tk)
         mask = _tile_mask(i, j, TILE_Q, TILE_K, s_real, causal)
-        m_prev = m_ref[0]
+        m_prev = m_ref[0]                                  # (tq, 1)
         l_prev = l_ref[0]
-        m_new = jnp.maximum(m_prev,
-                            jnp.max(jnp.where(mask, s, NEG_INF), axis=1))
-        p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
+        m_new = jnp.maximum(m_prev, jnp.max(jnp.where(mask, s, NEG_INF),
+                                            axis=1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[0] = alpha * l_prev + jnp.sum(p, axis=1)
-        o_ref[0] = o_ref[0] * alpha[:, None] + jax.lax.dot(
+        l_ref[0] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        o_ref[0] = o_ref[0] * alpha + jax.lax.dot(
             p, v, preferred_element_type=jnp.float32)
         m_ref[0] = m_new
 
     @pl.when(j == nk - 1)
     def _finalize():
         l = jnp.maximum(l_ref[0], _TINY)
-        o_ref[0] = o_ref[0] / l[:, None]
+        o_ref[0] = o_ref[0] / l
         lse_ref[0] = m_ref[0] + jnp.log(l)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("scale", "causal", "s_real", "interpret"))
 def _fwd_padded(q, k, v, scale: float, causal: bool, s_real: int,
-                interpret: bool):
+                interpret: Optional[bool]):
     BH, Sp, Dp = q.shape
     nq, nk = Sp // TILE_Q, Sp // TILE_K
+    # per-row stats as (BH, Sp, 1) columns: the TPU tiles the last two
+    # block dims by (8, 128) unless they span the whole array
+    row_spec = pl.BlockSpec((1, TILE_Q, 1), lambda b, i, j: (b, i, 0))
     out, _m, _l, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           s_real=s_real, nk=nk),
@@ -117,17 +119,15 @@ def _fwd_padded(q, k, v, scale: float, causal: bool, s_real: int,
         ],
         out_specs=[
             pl.BlockSpec((1, TILE_Q, Dp), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, TILE_Q), lambda b, i, j: (b, i)),
-            pl.BlockSpec((1, TILE_Q), lambda b, i, j: (b, i)),
-            pl.BlockSpec((1, TILE_Q), lambda b, i, j: (b, i)),
+            row_spec, row_spec, row_spec,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Sp, Dp), jnp.float32),
-            jax.ShapeDtypeStruct((BH, Sp), jnp.float32),
-            jax.ShapeDtypeStruct((BH, Sp), jnp.float32),
-            jax.ShapeDtypeStruct((BH, Sp), jnp.float32),
+            jax.ShapeDtypeStruct((BH, Sp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((BH, Sp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((BH, Sp, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
     return out, lse
 
@@ -145,7 +145,7 @@ def _p_tile(q_ref, k_ref, lse_ref, i, j, *, scale, causal, s_real):
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
     mask = _tile_mask(i, j, TILE_Q, TILE_K, s_real, causal)
-    return jnp.where(mask, jnp.exp(s - lse_ref[0][:, None]), 0.0)
+    return jnp.where(mask, jnp.exp(s - lse_ref[0]), 0.0)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
@@ -168,7 +168,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
         v = v_ref[0].astype(jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, None]) * scale
+        ds = p * (dp - delta_ref[0]) * scale
         dq_ref[0] += jax.lax.dot(ds, k_ref[0].astype(jnp.float32),
                                  preferred_element_type=jnp.float32)
 
@@ -197,7 +197,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dp = jax.lax.dot_general(do, v_ref[0].astype(jnp.float32),
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, None]) * scale
+        ds = p * (dp - delta_ref[0]) * scale
         dk_ref[0] += jax.lax.dot_general(
             ds, q_ref[0].astype(jnp.float32), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -206,12 +206,12 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 @functools.partial(jax.jit,
                    static_argnames=("scale", "causal", "s_real", "interpret"))
 def _bwd_padded(q, k, v, do, lse, delta, scale: float, causal: bool,
-                s_real: int, interpret: bool):
+                s_real: int, interpret: Optional[bool]):
     BH, Sp, Dp = q.shape
     nq, nk = Sp // TILE_Q, Sp // TILE_K
     q_spec = pl.BlockSpec((1, TILE_Q, Dp), lambda b, i, j: (b, i, 0))
     k_spec = pl.BlockSpec((1, TILE_K, Dp), lambda b, i, j: (b, j, 0))
-    row_spec = pl.BlockSpec((1, TILE_Q), lambda b, i, j: (b, i))
+    row_spec = pl.BlockSpec((1, TILE_Q, 1), lambda b, i, j: (b, i, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           s_real=s_real),
@@ -219,12 +219,12 @@ def _bwd_padded(q, k, v, do, lse, delta, scale: float, causal: bool,
         in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((BH, Sp, Dp), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v, do, lse, delta)
     # dk/dv: key tiles on the slow axis, query tiles revisit the outputs
     qT_spec = pl.BlockSpec((1, TILE_Q, Dp), lambda b, j, i: (b, i, 0))
     kT_spec = pl.BlockSpec((1, TILE_K, Dp), lambda b, j, i: (b, j, 0))
-    rowT_spec = pl.BlockSpec((1, TILE_Q), lambda b, j, i: (b, i))
+    rowT_spec = pl.BlockSpec((1, TILE_Q, 1), lambda b, j, i: (b, i, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           s_real=s_real),
@@ -233,7 +233,7 @@ def _bwd_padded(q, k, v, do, lse, delta, scale: float, causal: bool,
         out_specs=[kT_spec, kT_spec],
         out_shape=[jax.ShapeDtypeStruct((BH, Sp, Dp), jnp.float32),
                    jax.ShapeDtypeStruct((BH, Sp, Dp), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -250,12 +250,12 @@ def _pad(x, sp, dp):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash(q, k, v, causal: bool, interpret: bool):
+def _flash(q, k, v, causal: bool, interpret: Optional[bool]):
     out, _ = _flash_fwd(q, k, v, causal, interpret)
     return out
 
 
-def _flash_fwd(q, k, v, causal: bool, interpret: bool):
+def _flash_fwd(q, k, v, causal: bool, interpret: Optional[bool]):
     BH, S, D = q.shape
     sp = -(-S // TILE_Q) * TILE_Q
     dp = max(8, -(-D // 8) * 8)
@@ -268,13 +268,14 @@ def _flash_fwd(q, k, v, causal: bool, interpret: bool):
     return out[:, :S, :D].astype(q.dtype), (q, k, v, out, lse)
 
 
-def _flash_bwd(causal: bool, interpret: bool, res, g):
+def _flash_bwd(causal: bool, interpret: Optional[bool], res, g):
     q, k, v, out_p, lse = res             # out_p/lse are padded f32
     BH, S, D = q.shape
     sp, dp = out_p.shape[1], out_p.shape[2]
     scale = 1.0 / math.sqrt(D)
     do = _pad(g.astype(jnp.float32), sp, dp)
-    delta = jnp.sum(do * out_p, axis=-1)  # (BH, Sp); zero on padded rows
+    # (BH, Sp, 1); zero on padded rows
+    delta = jnp.sum(do * out_p, axis=-1, keepdims=True)
     dq, dk, dv = _bwd_padded(
         _pad(q, sp, dp).astype(jnp.float32),
         _pad(k, sp, dp).astype(jnp.float32),
@@ -289,7 +290,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q, k, v, causal: bool = True,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: Optional[bool] = None) -> jnp.ndarray:
     """Tiled online-softmax attention with a flash backward.
 
     Args:
@@ -297,8 +298,8 @@ def flash_attention(q, k, v, causal: bool = True,
             before calling; ``models/attention`` does).
         causal: apply the causal mask (key j visible to query i iff
             j <= i).
-        interpret: Pallas interpret mode (CPU container default; resolve
-            via ``repro.kernels.ops.get_interpret``).
+        interpret: Pallas interpret mode; None compiles on a TPU and
+            interprets elsewhere (:func:`repro.kernels.resolve_interpret`).
 
     Returns:
         (B, H, S, D) attention output in ``q.dtype``; differentiable via
@@ -306,5 +307,5 @@ def flash_attention(q, k, v, causal: bool = True,
     """
     B, H, S, D = q.shape
     flat = lambda x: x.reshape(B * H, S, x.shape[-1])
-    out = _flash(flat(q), flat(k), flat(v), bool(causal), bool(interpret))
+    out = _flash(flat(q), flat(k), flat(v), bool(causal), interpret)
     return out.reshape(B, H, S, D)

@@ -4,10 +4,10 @@ The aggregation stage touches every parameter of every selected client once
 per round — a pure memory-bound streaming reduction.  TPU mapping: the
 flattened update matrix (N clients × D params) is tiled along *both* axes
 with a 2-D grid ``(D-tiles × client-chunks)``; each grid step loads one
-(TILE_N, TILE_D) block into VMEM and accumulates its contribution into the
-output tile on the MXU:
+(TILE_N, TILE_D) block into VMEM and accumulates its weighted column sum
+into the output tile:
 
-    out[tile_d] += w[chunk] @ updates[chunk, tile_d]   # (1,TILE_N)x(TILE_N,TILE_D)
+    out[tile_d] += sum(w[chunk, None] * updates[chunk, tile_d], axis=0)
 
 The client-chunk axis is the fastest grid dimension, so all chunks of one
 D-tile revisit the same output block consecutively (the standard Pallas
@@ -50,6 +50,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 TILE_D = 2048
 TILE_N = 8
@@ -118,17 +120,17 @@ def _agg_kernel(w_ref, u_ref, o_ref):
     def _zero():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    w = w_ref[...]                     # (1, TILE_N) f32
-    u = u_ref[...]                     # (TILE_N, TILE_D) f32
-    o_ref[...] += jax.lax.dot_general(
-        w, u, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    # (TILE_N, 1) weights broadcast over the (TILE_N, TILE_D) rows, summed
+    # over the sublanes: an exact f32 weighted sum on the VPU (the MXU's
+    # default f32 passes would round the updates to bf16)
+    o_ref[...] += jnp.sum(w_ref[...] * u_ref[...], axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("interpret", "tile_d", "tile_n"))
 def _aggregate_padded(updates: jnp.ndarray, weights: jnp.ndarray,
-                      interpret: bool, tile_d: int, tile_n: int) -> jnp.ndarray:
+                      interpret: Optional[bool], tile_d: int,
+                      tile_n: int) -> jnp.ndarray:
     N, D = updates.shape
     pad = (-D) % tile_d
     if pad:
@@ -138,19 +140,21 @@ def _aggregate_padded(updates: jnp.ndarray, weights: jnp.ndarray,
         _agg_kernel,
         grid=(Dp // tile_d, N // tile_n),
         in_specs=[
-            pl.BlockSpec((1, tile_n), lambda i, j: (0, j)),
+            # weights as an (N, 1) column: the TPU tiles the last two
+            # block dims by (8, 128) unless they span the whole array
+            pl.BlockSpec((tile_n, 1), lambda i, j: (j, 0)),
             pl.BlockSpec((tile_n, tile_d), lambda i, j: (j, i)),
         ],
         out_specs=pl.BlockSpec((1, tile_d), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, Dp), jnp.float32),
-        interpret=interpret,
-    )(weights.reshape(1, N).astype(jnp.float32),
+        interpret=resolve_interpret(interpret),
+    )(weights.reshape(N, 1).astype(jnp.float32),
       updates.astype(jnp.float32))
     return out[0, :D]
 
 
 def fedavg_aggregate(updates: jnp.ndarray, weights: jnp.ndarray,
-                     interpret: bool = True, tile_d: int = TILE_D,
+                     interpret: Optional[bool] = None, tile_d: int = TILE_D,
                      tile_n: int = TILE_N,
                      staleness: Optional[jnp.ndarray] = None,
                      staleness_power: float = 0.5) -> jnp.ndarray:
@@ -160,8 +164,8 @@ def fedavg_aggregate(updates: jnp.ndarray, weights: jnp.ndarray,
         updates: (N, D) f32 — one flattened update vector per client.
         weights: (N,) aggregation weights summing to 1 (FedAvg sample
             weights; see :func:`repro.core.aggregation.fedavg_weights`).
-        interpret: True executes the kernel body on CPU (this container);
-            on TPU pass False for the compiled kernel.
+        interpret: Pallas interpret mode; None compiles on a TPU and
+            interprets elsewhere (:func:`repro.kernels.resolve_interpret`).
         tile_d, tile_n: VMEM block shape; peak VMEM is tile_n*tile_d*4 B
             regardless of N.
         staleness: optional (N,) per-update staleness; when given, weights
@@ -185,7 +189,7 @@ def fedavg_aggregate(updates: jnp.ndarray, weights: jnp.ndarray,
 
 
 def _tier_reduce(updates: jnp.ndarray, weights: jnp.ndarray,
-                 use_kernel: bool, interpret: bool, tile_d: int,
+                 use_kernel: bool, interpret: Optional[bool], tile_d: int,
                  tile_n: int) -> jnp.ndarray:
     """One tier of the reduction tree: (G, F, D) x (G, F) -> (G, D).
 
@@ -199,13 +203,14 @@ def _tier_reduce(updates: jnp.ndarray, weights: jnp.ndarray,
             lambda wu: _aggregate_padded(wu[1], wu[0], interpret, tile_d,
                                          tile_n),
             (weights, updates))
-    return jnp.einsum("gf,gfd->gd", weights, updates)
+    return jnp.einsum("gf,gfd->gd", weights, updates,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 @functools.partial(jax.jit, static_argnames=("fanout", "use_kernel",
                                              "interpret", "tile_d", "tile_n"))
 def _tree_padded(updates: jnp.ndarray, weights: jnp.ndarray, fanout: int,
-                 use_kernel: bool, interpret: bool, tile_d: int,
+                 use_kernel: bool, interpret: Optional[bool], tile_d: int,
                  tile_n: int) -> jnp.ndarray:
     """Edge→region→global reduction tree over (N, D) rows.
 
@@ -234,7 +239,7 @@ def _tree_padded(updates: jnp.ndarray, weights: jnp.ndarray, fanout: int,
 
 
 def fedavg_aggregate_tree(updates: jnp.ndarray, weights: jnp.ndarray,
-                          fanout: int = 0, interpret: bool = True,
+                          fanout: int = 0, interpret: Optional[bool] = None,
                           use_kernel: bool = True, tile_d: int = TILE_D,
                           tile_n: int = TILE_N,
                           staleness: Optional[jnp.ndarray] = None,
@@ -267,7 +272,8 @@ def fedavg_aggregate_tree(updates: jnp.ndarray, weights: jnp.ndarray,
             return fedavg_aggregate(updates, weights, interpret=interpret,
                                     tile_d=tile_d, tile_n=tile_n)
         return jnp.einsum("n,nd->d", weights,
-                          updates.astype(jnp.float32))
+                          updates.astype(jnp.float32),
+                          precision=jax.lax.Precision.HIGHEST)
     updates, weights = pad_cohort(updates.astype(jnp.float32), weights,
                                   tile_n if use_kernel else 1)
     return _tree_padded(updates, weights, int(fanout), use_kernel,
@@ -276,7 +282,8 @@ def fedavg_aggregate_tree(updates: jnp.ndarray, weights: jnp.ndarray,
 
 def fedavg_aggregate_sharded(updates: jnp.ndarray, weights: jnp.ndarray,
                              mesh, axis: str = "clients",
-                             interpret: bool = True, tile_d: int = TILE_D,
+                             interpret: Optional[bool] = None,
+                             tile_d: int = TILE_D,
                              tile_n: int = TILE_N,
                              staleness: Optional[jnp.ndarray] = None,
                              staleness_power: float = 0.5,
@@ -327,7 +334,7 @@ def fedavg_aggregate_sharded(updates: jnp.ndarray, weights: jnp.ndarray,
 
 
 @functools.lru_cache(maxsize=32)
-def _sharded_program(mesh, axis: str, interpret: bool, tile_d: int,
+def _sharded_program(mesh, axis: str, interpret: Optional[bool], tile_d: int,
                      tile_n: int, fanout: int = 0):
     """Jitted shard_map program, cached per (mesh, tiling, fanout) — an
     uncached shard_map retraces every call (~200ms/round), defeating the

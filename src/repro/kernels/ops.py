@@ -1,93 +1,70 @@
-"""Jit'd public wrappers for the Pallas kernels.
+"""Public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True because this container is CPU-only; on real
-TPU hardware set ``REPRO_KERNEL_INTERPRET=0`` (or pass interpret=False, or
-call :func:`set_interpret`) to run the compiled kernels.  The env flag is
-re-read on every call so tests/benchmarks can toggle compiled vs interpret
-mode without reloading the module.
+``interpret=None`` (the default everywhere) compiles the kernels when the
+default backend is a TPU and runs them in the Pallas interpreter elsewhere
+(:func:`repro.kernels.resolve_interpret`); pass a bool to force either.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax.numpy as jnp
 
 from repro.kernels import attention, fedavg_agg, quant, rwkv6_scan, stc_topk
 
-# Process-wide override installed via set_interpret(); None defers to the env.
-_OVERRIDE: Optional[bool] = None
 
-
-def set_interpret(mode: Optional[bool]) -> None:
-    """Force interpret mode on/off for all kernel calls; None -> env flag."""
-    global _OVERRIDE
-    _OVERRIDE = mode
-
-
-def get_interpret(interpret: Optional[bool] = None) -> bool:
-    """Resolve the effective interpret flag for one call.
-
-    Per-call argument beats the set_interpret() override beats the
-    REPRO_KERNEL_INTERPRET env var (read per call, not at import).
-    """
-    if interpret is not None:
-        return interpret
-    if _OVERRIDE is not None:
-        return _OVERRIDE
-    return os.environ.get("REPRO_KERNEL_INTERPRET", "1") != "0"
-
-
-def fedavg_aggregate(updates, weights, interpret: bool = None,
+def fedavg_aggregate(updates, weights, interpret: Optional[bool] = None,
                      staleness=None, staleness_power: float = 0.5):
     return fedavg_agg.fedavg_aggregate(
-        updates, weights, interpret=get_interpret(interpret),
+        updates, weights, interpret=interpret,
         staleness=staleness, staleness_power=staleness_power)
 
 
-def stc_compress(x, keep_frac: float = 0.01, interpret: bool = None):
-    return stc_topk.stc_compress(x, keep_frac, interpret=get_interpret(interpret))
+def stc_compress(x, keep_frac: float = 0.01,
+                 interpret: Optional[bool] = None):
+    return stc_topk.stc_compress(x, keep_frac, interpret=interpret)
 
 
-def stc_compress_batched(x, keep_frac: float = 0.01, interpret: bool = None,
-                         mesh=None):
+def stc_compress_batched(x, keep_frac: float = 0.01,
+                         interpret: Optional[bool] = None, mesh=None):
     """Stacked-cohort STC: (N, D) -> (sparsified (N, D), nnz (N,)).
 
     With ``mesh`` (1-D client mesh), each shard compresses its own client
     rows in place (no gather, no collective)."""
-    itp = get_interpret(interpret)
     if mesh is not None:
         return stc_topk.stc_compress_batched_sharded(
-            x, float(keep_frac), mesh, interpret=itp)
-    return stc_topk.stc_compress_batched(x, float(keep_frac), interpret=itp)
+            x, float(keep_frac), mesh, interpret=interpret)
+    return stc_topk.stc_compress_batched(x, float(keep_frac),
+                                         interpret=interpret)
 
 
-def int8_roundtrip_batched(x, interpret: bool = None, mesh=None):
+def int8_roundtrip_batched(x, interpret: Optional[bool] = None, mesh=None):
     """Stacked-cohort int8 quantize→dequantize with per-client scales:
     (N, D) -> (sent (N, D), scale (N,)); sharded per client row under
     ``mesh``."""
-    itp = get_interpret(interpret)
     if mesh is not None:
-        return quant.int8_roundtrip_batched_sharded(x, mesh, interpret=itp)
-    return quant.int8_roundtrip_batched(x, interpret=itp)
+        return quant.int8_roundtrip_batched_sharded(x, mesh,
+                                                    interpret=interpret)
+    return quant.int8_roundtrip_batched(x, interpret=interpret)
 
 
-def quantize(x, interpret: bool = None):
-    return quant.quantize(x, interpret=get_interpret(interpret))
+def quantize(x, interpret: Optional[bool] = None):
+    return quant.quantize(x, interpret=interpret)
 
 
-def dequantize(q, s, shape, dtype=jnp.float32, interpret: bool = None):
-    return quant.dequantize(
-        q, s, tuple(shape), dtype, interpret=get_interpret(interpret))
+def dequantize(q, s, shape, dtype=jnp.float32,
+               interpret: Optional[bool] = None):
+    return quant.dequantize(q, s, tuple(shape), dtype, interpret=interpret)
 
 
-def flash_attention(q, k, v, causal: bool = True, interpret: bool = None):
+def flash_attention(q, k, v, causal: bool = True,
+                    interpret: Optional[bool] = None):
     """Tiled online-softmax attention, (B, H, S, D) MHA layout, with a
     flash backward (probs recomputed from the saved log-sum-exp)."""
     return attention.flash_attention(
-        q, k, v, causal=causal, interpret=get_interpret(interpret))
+        q, k, v, causal=causal, interpret=interpret)
 
 
-def wkv6(r, k, v, logw, u, s0, interpret: bool = None):
+def wkv6(r, k, v, logw, u, s0, interpret: Optional[bool] = None):
     return rwkv6_scan.wkv6(
-        r, k, v, logw, u, s0, interpret=get_interpret(interpret))
+        r, k, v, logw, u, s0, interpret=interpret)

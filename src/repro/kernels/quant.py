@@ -22,10 +22,13 @@ independent — no collective).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 TILE_R = 8
 TILE_C = 1024
@@ -38,11 +41,11 @@ def _quant_kernel(x_ref, q_ref, s_ref):
     scale = jnp.maximum(jnp.max(jnp.abs(x)), 1e-12) / 127.0
     q = jnp.clip(jnp.round(x / scale), -127.0, 127.0)
     q_ref[...] = q.astype(jnp.int8)
-    s_ref[0, 0] = scale
+    s_ref[...] = jnp.full(s_ref.shape, scale, jnp.float32)
 
 
 def _dequant_kernel(q_ref, s_ref, o_ref):
-    o_ref[...] = (q_ref[...].astype(jnp.float32) * s_ref[0, 0]).astype(
+    o_ref[...] = (q_ref[...].astype(jnp.float32) * s_ref[0]).astype(
         o_ref.dtype)
 
 
@@ -57,41 +60,43 @@ def _tile(x):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def quantize(x: jnp.ndarray, interpret: bool = True):
-    """-> (q int8 tiled (R, C), scales (grid, 1), meta) for dequantize."""
+def quantize(x: jnp.ndarray, interpret: Optional[bool] = None):
+    """-> (q int8 tiled (R, C), scales (grid, 1)) for dequantize."""
     x2, grid, pad = _tile(x)
+    # one scale per tile, as a (1, 1) block of a (grid, 1, 1) array: the
+    # TPU accepts a block whose last two dims span the whole array
     q, s = pl.pallas_call(
         _quant_kernel,
         grid=(grid,),
         in_specs=[pl.BlockSpec((TILE_R, TILE_C), lambda i: (i, 0))],
         out_specs=[
             pl.BlockSpec((TILE_R, TILE_C), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(x2.shape, jnp.int8),
-            jax.ShapeDtypeStruct((grid, 1), jnp.float32),
+            jax.ShapeDtypeStruct((grid, 1, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x2)
-    return q, s
+    return q, s.reshape(grid, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("shape", "dtype", "interpret"))
 def dequantize(q: jnp.ndarray, s: jnp.ndarray, shape, dtype=jnp.float32,
-               interpret: bool = True) -> jnp.ndarray:
+               interpret: Optional[bool] = None) -> jnp.ndarray:
     grid = s.shape[0]
     out = pl.pallas_call(
         _dequant_kernel,
         grid=(grid,),
         in_specs=[
             pl.BlockSpec((TILE_R, TILE_C), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((TILE_R, TILE_C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, dtype),
-        interpret=interpret,
-    )(q, s)
+        interpret=resolve_interpret(interpret),
+    )(q, s.reshape(grid, 1, 1))
     size = 1
     for d in shape:
         size *= d
@@ -122,7 +127,8 @@ def _qdq_kernel(x_ref, s_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "tile_d"))
-def _int8_roundtrip_padded(x: jnp.ndarray, interpret: bool, tile_d: int):
+def _int8_roundtrip_padded(x: jnp.ndarray, interpret: Optional[bool],
+                           tile_d: int):
     N, D = x.shape                     # pre-padded to the block grid
     grid = (N // TILE_B, D // tile_d)
     m = pl.pallas_call(
@@ -131,7 +137,7 @@ def _int8_roundtrip_padded(x: jnp.ndarray, interpret: bool, tile_d: int):
         in_specs=[pl.BlockSpec((TILE_B, tile_d), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((TILE_B, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, 1), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x)
     # explicit reciprocal multiply: XLA strength-reduces `m / 127.0` to a
     # 1-ulp-off reciprocal multiply under jit, which would break bitwise
@@ -146,12 +152,12 @@ def _int8_roundtrip_padded(x: jnp.ndarray, interpret: bool, tile_d: int):
         ],
         out_specs=pl.BlockSpec((TILE_B, tile_d), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, scale)
     return sent, scale
 
 
-def int8_roundtrip_batched(x: jnp.ndarray, interpret: bool = True,
+def int8_roundtrip_batched(x: jnp.ndarray, interpret: Optional[bool] = None,
                            tile_d: int = TILE_SEG):
     """Quantize→dequantize a stacked (N, D) cohort update with per-row
     (= per-client per-tensor) scales.
@@ -172,7 +178,7 @@ def int8_roundtrip_batched(x: jnp.ndarray, interpret: bool = True,
 
 
 @functools.lru_cache(maxsize=32)
-def _int8_batched_sharded_program(mesh, axis: str, interpret: bool,
+def _int8_batched_sharded_program(mesh, axis: str, interpret: Optional[bool],
                                   tile_d: int):
     from jax.sharding import PartitionSpec as P
 
@@ -187,7 +193,7 @@ def _int8_batched_sharded_program(mesh, axis: str, interpret: bool,
 
 def int8_roundtrip_batched_sharded(x: jnp.ndarray, mesh,
                                    axis: str = "clients",
-                                   interpret: bool = True,
+                                   interpret: Optional[bool] = None,
                                    tile_d: int = TILE_SEG):
     """Mesh-sharded :func:`int8_roundtrip_batched` (per-shard rows, no
     collective).  N must be divisible by ``mesh.size``."""

@@ -13,7 +13,8 @@ from repro.kernels.stc_topk import TILE_R as STC_R
 def fedavg_ref(updates: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
     """(N, D), (N,) -> (D,)."""
     return jnp.einsum("n,nd->d", weights.astype(jnp.float32),
-                      updates.astype(jnp.float32))
+                      updates.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def _stc_tile_ref(x, keep_frac, real):

@@ -41,10 +41,13 @@ Two entry points:
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 TILE_R = 8
 TILE_C = 1024
@@ -89,7 +92,7 @@ def _stc_kernel(x_ref, o_ref, *, keep_frac: float, n_real: int):
 
 @functools.partial(jax.jit, static_argnames=("keep_frac", "interpret"))
 def stc_compress(x: jnp.ndarray, keep_frac: float = 0.01,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: Optional[bool] = None) -> jnp.ndarray:
     """Dense STC: returns the sparsified/ternarized tensor (same shape)."""
     shape = x.shape
     flat = x.reshape(-1)
@@ -105,7 +108,7 @@ def stc_compress(x: jnp.ndarray, keep_frac: float = 0.01,
         in_specs=[pl.BlockSpec((TILE_R, TILE_C), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((TILE_R, TILE_C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x2)
     return out.reshape(-1)[: flat.size - pad].reshape(shape)
 
@@ -153,7 +156,7 @@ def _stc_batched_kernel(x_ref, o_ref, nnz_ref, *, keep_frac: float,
                    static_argnames=("keep_frac", "d_real", "interpret",
                                     "tile_d"))
 def _stc_batched_padded(x: jnp.ndarray, keep_frac: float, d_real: int,
-                        interpret: bool, tile_d: int):
+                        interpret: Optional[bool], tile_d: int):
     N, D = x.shape                      # pre-padded: N % TILE_B == D % tile_d == 0
     out, nnz = pl.pallas_call(
         functools.partial(_stc_batched_kernel, keep_frac=keep_frac,
@@ -168,19 +171,21 @@ def _stc_batched_padded(x: jnp.ndarray, keep_frac: float, d_real: int,
             jax.ShapeDtypeStruct(x.shape, jnp.float32),
             jax.ShapeDtypeStruct((N, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x)
     return out, nnz
 
 
 def stc_compress_batched(x: jnp.ndarray, keep_frac: float = 0.01,
-                         interpret: bool = True, tile_d: int = TILE_SEG):
+                         interpret: Optional[bool] = None,
+                         tile_d: int = TILE_SEG):
     """Sparsify a stacked (N, D) cohort update in one pallas_call.
 
     Args:
         x: (N, D) — one flattened update row per client.
         keep_frac: per-tile keep fraction (``client.stc_sparsity``).
-        interpret: Pallas interpret mode (CPU container default).
+        interpret: Pallas interpret mode; None compiles on a TPU and
+            interprets elsewhere (:func:`repro.kernels.resolve_interpret`).
         tile_d: elements per per-row threshold tile; the default
             ``TILE_SEG`` (8192) makes each row's tiles the same element
             groups as the dense kernel / compression stage, so per-client
@@ -202,7 +207,7 @@ def stc_compress_batched(x: jnp.ndarray, keep_frac: float = 0.01,
 
 @functools.lru_cache(maxsize=32)
 def _stc_batched_sharded_program(mesh, axis: str, keep_frac: float,
-                                 interpret: bool, tile_d: int):
+                                 interpret: Optional[bool], tile_d: int):
     """Jitted shard_map program, cached per (mesh, keep_frac, tiling) —
     same rationale as ``fedavg_agg._sharded_program``: an uncached
     shard_map retraces every call."""
@@ -219,7 +224,7 @@ def _stc_batched_sharded_program(mesh, axis: str, keep_frac: float,
 
 def stc_compress_batched_sharded(x: jnp.ndarray, keep_frac: float, mesh,
                                  axis: str = "clients",
-                                 interpret: bool = True,
+                                 interpret: Optional[bool] = None,
                                  tile_d: int = TILE_SEG):
     """Mesh-sharded :func:`stc_compress_batched`: each shard sparsifies its
     own client rows (rows are independent — no collective), so compressed

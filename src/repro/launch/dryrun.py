@@ -15,6 +15,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 import argparse
 import dataclasses
 import json
+import sys
 import time
 import traceback
 
@@ -128,8 +129,7 @@ def dryrun(arch: str, shape_name: str, multi_pod: bool = False,
                 "decode": "serve"}[shape.kind]
 
     t0 = time.time()
-    from repro.models.sharding import use_mesh
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if step == "train":
             state_abs, state_shard, _ = build_state_specs(
                 model, optimizer, mesh, rules)
@@ -325,6 +325,7 @@ def main():
 
     archs = list_archs() if args.arch == "all" else [args.arch]
     shapes = list(SHAPES) if args.shape == "all" else [args.shape]
+    failed = 0
     for a in archs:
         for s in shapes:
             try:
@@ -348,6 +349,9 @@ def main():
             except Exception:
                 print(f"[FAIL] {a} {s}")
                 traceback.print_exc()
+                failed += 1
+    if failed:
+        sys.exit(f"{failed} dry run(s) failed")
 
 
 if __name__ == "__main__":

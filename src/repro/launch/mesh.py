@@ -7,6 +7,8 @@ from __future__ import annotations
 
 import jax
 
+from repro.models.sharding import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     """Single pod: (16, 16) = 256 chips, axes (data, model).
@@ -22,7 +24,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh needs {n} devices but only {len(devices)} present — "
             "run via repro.launch.dryrun (sets "
             "--xla_force_host_platform_device_count=512)")
-    return jax.make_mesh(shape, axes, devices=devices)
+    return make_mesh(shape, axes, devices=devices)
 
 
 def make_host_mesh(shape=(2, 2), axes=("data", "model")):
@@ -30,4 +32,4 @@ def make_host_mesh(shape=(2, 2), axes=("data", "model")):
     n = 1
     for s in shape:
         n *= s
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return make_mesh(shape, axes, devices=jax.devices()[:n])

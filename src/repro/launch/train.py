@@ -2,7 +2,7 @@
 
 Trains a (reduced or full) arch config with the production train_step on
 whatever devices exist — the same code path the dry-run lowers for the
-(16,16) mesh.  On this CPU container:
+(16,16) mesh.  On a CPU host:
 
     PYTHONPATH=src python -m repro.launch.train --arch glm4-9b --reduced \
         --steps 200 --batch 8 --seq 256
@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.checkpoint import save_checkpoint
 from repro.configs import get_arch, list_archs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import (
     Model, init_train_state, make_train_step,
 )
@@ -65,6 +66,7 @@ def main(argv=None):
     ap.add_argument("--d-ff", type=int, default=0)
     ap.add_argument("--vocab", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     import dataclasses
     cfg = get_arch(args.arch, reduced=args.reduced)

@@ -144,7 +144,17 @@ def merge_lora(base_params: PyTree, adapters: Dict[str, Dict[str, Any]],
         if ab is None:
             merged.append(w)
             continue
-        delta = jnp.matmul(ab["a"], ab["b"])      # batches leading dims
+        # A@B as r rank-1 updates added in a fixed order, not a matmul: a
+        # matmul sums over the rank in an order that changes with a leading
+        # client (vmap) axis, so sequential and batched cohorts would drift
+        # apart.  The chain fuses into one elementwise pass with no
+        # (d_in, r, d_out) temporary, and it stays f32 on the TPU, whose
+        # default f32 matmul rounds the factors to bf16.
+        a = ab["a"].astype(jnp.float32)
+        b = ab["b"].astype(jnp.float32)
+        delta = a[..., :, 0, None] * b[..., None, 0, :]
+        for k in range(1, a.shape[-1]):
+            delta = delta + a[..., :, k, None] * b[..., None, k, :]
         merged.append(
             (w.astype(jnp.float32)
              + jnp.float32(scale) * delta.reshape(w.shape)).astype(w.dtype))

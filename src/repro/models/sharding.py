@@ -1,12 +1,15 @@
-"""Sharding hints that degrade gracefully outside a mesh context.
+"""Sharding hints that are a no-op outside a mesh context.
 
 Models call ``hint(x, "batch", None, "model")`` with *logical* axis names;
-under an ambient mesh (``jax.sharding.use_mesh`` / ``with mesh:``) this turns
-into ``with_sharding_constraint``; with no mesh (CPU unit tests) it is a
-no-op.  Logical axes are resolved through the active rule table so the same
-model code serves the single-pod ("data","model") and multi-pod
+under an ambient mesh (``jax.set_mesh``) this turns into
+``with_sharding_constraint``; with no mesh (CPU unit tests) it is a no-op.
+Logical axes are resolved through the active rule table so the same model
+code serves the single-pod ("data","model") and multi-pod
 ("pod","data","model") meshes: "batch" -> ("pod","data") when a pod axis
-exists, else ("data",).
+exists, else ("data",).  Mesh axes that cannot take a constraint — manual
+axes inside a ``shard_map`` body, or axes that do not divide the dimension
+— are left out of the spec in :func:`resolve_spec`, so every constraint
+``hint`` emits is one the compiler accepts.
 """
 from __future__ import annotations
 
@@ -52,90 +55,43 @@ def use_rules(rules: Dict[str, Optional[Tuple[str, ...]]]):
 
 
 def current_mesh():
-    """Version-tolerant ambient-mesh lookup; None when no mesh is active.
-
-    ``jax.sharding.get_abstract_mesh`` only exists in newer JAX releases;
-    older releases (and ``with mesh:`` blocks on every release) record the
-    mesh in the pxla thread-local resource env.  Try the new API first,
-    then the thread-local, and treat an empty mesh as "no mesh".
-    """
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:
-        try:
-            m = get_am()
-            if m is not None and not getattr(m, "empty", True):
-                return m
-        except Exception:
-            pass
-    try:
-        from jax.interpreters import pxla
-        m = pxla.thread_resources.env.physical_mesh
-        if m is not None and not getattr(m, "empty", True):
-            return m
-    except Exception:
-        pass
-    return None
-
-
-@contextmanager
-def use_mesh(mesh):
-    """Enter ``mesh`` as the ambient mesh on any supported JAX version
-    (``jax.set_mesh`` / ``jax.sharding.use_mesh`` / ``with mesh:``)."""
-    setter = getattr(jax, "set_mesh", None) or \
-        getattr(jax.sharding, "use_mesh", None)
-    if setter is not None:
-        with setter(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+    """The ambient (abstract) mesh set by ``jax.set_mesh``; None when no
+    mesh is active."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` across the axis_types API change: newer JAX wants
-    explicit Auto axis types for sharding propagation; older JAX has no
-    such kwarg (everything is Auto)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                axis_shapes, axis_names, devices=devices,
-                axis_types=(axis_type.Auto,) * len(tuple(axis_names)))
-        except TypeError:
-            pass
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
+    """``jax.make_mesh`` with ``Auto`` axes, so sharding constraints and
+    propagation behave as GSPMD hints (the default ``Explicit`` axes turn
+    every ``with_sharding_constraint`` into a typed-sharding assertion)."""
+    return jax.make_mesh(
+        axis_shapes, axis_names, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)))
 
 
 def shard_map(f, mesh, in_specs, out_specs, manual_axes=None):
-    """Full/partial-manual shard_map across the API rename.
-
-    Newer JAX: ``jax.shard_map(..., axis_names=..., check_vma=False)``.
-    Older JAX: ``jax.experimental.shard_map.shard_map(..., check_rep=False,
-    auto=<non-manual axes>)``.  ``manual_axes`` defaults to every mesh axis
-    (full-manual).
-    """
-    manual = frozenset(manual_axes if manual_axes is not None
-                       else mesh.axis_names)
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  axis_names=set(manual), check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    auto = frozenset(mesh.axis_names) - manual
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False, auto=auto)
-
-
-def _ambient_mesh():
-    return current_mesh()
+    """Full- or partial-manual ``jax.shard_map``; ``manual_axes`` defaults
+    to every mesh axis (full-manual)."""
+    manual = set(manual_axes if manual_axes is not None
+                 else mesh.axis_names)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=manual,
+                         check_vma=False)
 
 
 def resolve_spec(logical: Tuple[Optional[str], ...], shape=None) -> Optional[P]:
-    """Resolve logical axis names to a PartitionSpec for the ambient mesh."""
-    mesh = _ambient_mesh()
+    """Resolve logical axis names to a PartitionSpec for the ambient mesh.
+
+    A logical axis stays replicated (``None``) when its rule names no mesh
+    axis that is present, not yet used by an earlier dim, and not manual
+    (inside ``shard_map`` the body already owns those axes), or when the
+    mesh axes' product does not divide ``shape[i]``.  Returns None when no
+    mesh is active."""
+    mesh = current_mesh()
     if mesh is None:
         return None
-    mesh_axes = set(mesh.axis_names)
+    mesh_axes = set(mesh.axis_names) - set(mesh.manual_axes)
     sizes = dict(mesh.shape)  # {axis_name: size}
     rules = current_rules()
     parts = []
@@ -169,7 +125,4 @@ def hint(x, *logical: Optional[str]):
     spec = resolve_spec(tuple(logical), shape=getattr(x, "shape", None))
     if spec is None:
         return x
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except (ValueError, RuntimeError, TypeError):
-        return x
+    return jax.lax.with_sharding_constraint(x, spec)

@@ -90,24 +90,23 @@ def test_kernel_aggregation_matches_einsum_oracle_on_pytrees():
 
 
 # ---------------------------------------------------------------------------
-# interpret-mode toggling (kernels/ops.py)
+# interpret mode follows the platform (kernels/__init__.py)
 # ---------------------------------------------------------------------------
 
 
-def test_interpret_flag_read_per_call(monkeypatch):
-    monkeypatch.delenv("REPRO_KERNEL_INTERPRET", raising=False)
-    assert ops.get_interpret() is True
-    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "0")
-    assert ops.get_interpret() is False       # env re-read, no module reload
-    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "1")
-    assert ops.get_interpret() is True
-    ops.set_interpret(False)
-    try:
-        assert ops.get_interpret() is False   # setter beats env
-        assert ops.get_interpret(True) is True  # per-call arg beats setter
-    finally:
-        ops.set_interpret(None)
-    assert ops.get_interpret() is True
+def test_cpu_backend_resolves_to_interpret_mode():
+    from repro.kernels import resolve_interpret
+    assert jax.default_backend() == "cpu"
+    assert resolve_interpret() is True          # no TPU: interpreter
+    assert resolve_interpret(False) is False    # an explicit bool wins
+    assert resolve_interpret(True) is True
+    # the default-argument kernel path runs (interpreted) on CPU and
+    # matches the explicitly interpreted call bit for bit
+    u = jnp.asarray(np.random.RandomState(0).randn(8, 300), jnp.float32)
+    w = jnp.full((8,), 1.0 / 8, jnp.float32)
+    np.testing.assert_array_equal(
+        np.asarray(ops.fedavg_aggregate(u, w)),
+        np.asarray(ops.fedavg_aggregate(u, w, interpret=True)))
 
 
 # ---------------------------------------------------------------------------
@@ -198,7 +197,7 @@ def test_batched_rejects_mixed_batch_sizes():
     c2 = Client("b", model, data, ClientConfig(), batch_size=32)
     params = model.init(jax.random.PRNGKey(0))
     with pytest.raises(ValueError, match="uniform batch size"):
-        BatchedExecutor(model).run_cohort([c1, c2], params, 0)
+        BatchedExecutor(model, max_clients=64).run_cohort([c1, c2], params, 0)
 
 
 def test_batched_rejects_train_stage_override():
@@ -245,7 +244,7 @@ def test_batched_per_client_lr_matches_sequential():
         clients.append(Client(f"c{i}", model, data, cfg, batch_size=16))
     params = model.init(jax.random.PRNGKey(0))
 
-    batched = BatchedExecutor(model).run_cohort(clients, params, round_id=1)
+    batched = BatchedExecutor(model, max_clients=64).run_cohort(clients, params, round_id=1)
     for c, res in zip(clients, batched):
         seq = c.train(params, round_id=1)
         for a, b in zip(jax.tree_util.tree_leaves(seq["update"]),
@@ -275,7 +274,7 @@ def _assert_batched_matches_per_client_sequential(clients, rounds=2):
 
     model = clients[0].model
     params = model.init(jax.random.PRNGKey(0))
-    ex = BatchedExecutor(model)
+    ex = BatchedExecutor(model, max_clients=64)
     for r in range(rounds):
         batched = ex.run_cohort(clients, params, round_id=r)
         for c, res in zip(clients, batched):
@@ -344,7 +343,7 @@ def test_hetero_hyperparams_zero_recompiles_across_rounds():
     model = linear_model()
     clients = _hetero_clients(model, cfgs)
     params = model.init(jax.random.PRNGKey(0))
-    ex = BatchedExecutor(model)
+    ex = BatchedExecutor(model, max_clients=64)
     ex.run_cohort(clients, params, round_id=0)      # warm-up trace
     before = cohort_trace_count()
     for r in range(1, 4):
@@ -368,12 +367,12 @@ def test_batched_rejects_hand_assigned_per_client_optimizers():
     clients[0].optimizer = sgd(0.123)
     params = model.init(jax.random.PRNGKey(0))
     with pytest.raises(ValueError, match="hand-assigned"):
-        BatchedExecutor(model).run_cohort(clients, params, 0)
+        BatchedExecutor(model, max_clients=64).run_cohort(clients, params, 0)
     # uniform hand-built instance: allowed via the traced wrapper
     shared = sgd(0.05, momentum=0.9)
     for c in clients:
         c.optimizer = shared
-    res = BatchedExecutor(model).run_cohort(clients, params, 0)
+    res = BatchedExecutor(model, max_clients=64).run_cohort(clients, params, 0)
     assert len(res) == 2
 
 
@@ -399,9 +398,9 @@ def test_batched_rejects_mixed_optimizer_family_naming_clients():
     params = model.init(jax.random.PRNGKey(0))
     with pytest.raises(ValueError,
                        match=r"mix optimizer families.*adam_c.*sgd_a"):
-        BatchedExecutor(model).run_cohort([c1, c2, c3], params, 0)
+        BatchedExecutor(model, max_clients=64).run_cohort([c1, c2, c3], params, 0)
     # mixed momentum within one family no longer raises
-    results = BatchedExecutor(model).run_cohort([c1, c2], params, 0)
+    results = BatchedExecutor(model, max_clients=64).run_cohort([c1, c2], params, 0)
     assert len(results) == 2
 
 

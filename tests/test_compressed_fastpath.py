@@ -277,7 +277,7 @@ def test_cohort_data_pool_reuses_device_buffers():
     model = linear_model()
     clients = _pool_clients(model)
     params = model.init(jax.random.PRNGKey(0))
-    ex = BatchedExecutor(model)
+    ex = BatchedExecutor(model, max_clients=64)
     ex.run_cohort_stacked(clients, params, round_id=0)
     xd = ex._data_pool["x"]
     st_warm = ex.run_cohort_stacked(clients, params, round_id=1)
@@ -285,7 +285,7 @@ def test_cohort_data_pool_reuses_device_buffers():
     # permuted selection order: still a pure pool gather
     ex.run_cohort_stacked(clients[::-1], params, round_id=2)
     assert ex._data_pool["x"] is xd
-    cold = BatchedExecutor(model).run_cohort_stacked(clients, params,
+    cold = BatchedExecutor(model, max_clients=64).run_cohort_stacked(clients, params,
                                                      round_id=1)
     for a, b in zip(jax.tree_util.tree_leaves(st_warm["updates"]),
                     jax.tree_util.tree_leaves(cold["updates"])):
@@ -301,7 +301,7 @@ def test_cohort_data_pool_permutation_matches_per_client():
     model = linear_model()
     clients = _pool_clients(model)
     params = model.init(jax.random.PRNGKey(0))
-    ex = BatchedExecutor(model)
+    ex = BatchedExecutor(model, max_clients=64)
     fwd = ex.run_cohort(clients, params, round_id=1)
     rev = ex.run_cohort(clients[::-1], params, round_id=1)
     for c, res in zip(clients, fwd):
@@ -320,12 +320,12 @@ def test_data_pool_invalidate_rereads_mutated_data():
     model = linear_model()
     clients = _pool_clients(model, n=2)
     params = model.init(jax.random.PRNGKey(0))
-    ex = BatchedExecutor(model)
+    ex = BatchedExecutor(model, max_clients=64)
     ex.run_cohort_stacked(clients, params, round_id=0)
     clients[0].data.x[:] = clients[0].data.x[::-1]      # in-place mutation
     ex.invalidate_data(clients[0].client_id)
     warm = ex.run_cohort_stacked(clients, params, round_id=1)
-    cold = BatchedExecutor(model).run_cohort_stacked(clients, params,
+    cold = BatchedExecutor(model, max_clients=64).run_cohort_stacked(clients, params,
                                                      round_id=1)
     for a, b in zip(jax.tree_util.tree_leaves(warm["updates"]),
                     jax.tree_util.tree_leaves(cold["updates"])):
@@ -342,7 +342,7 @@ def test_data_pool_bounded_lru_eviction():
     model = linear_model()
     clients = _pool_clients(model, n=5)
     params = model.init(jax.random.PRNGKey(0))
-    ex = BatchedExecutor(model)
+    ex = BatchedExecutor(model, max_clients=64)
     ex.DATA_POOL_MAX_CLIENTS = 3
     ex.run_cohort_stacked(clients[:3], params, round_id=0)
     assert set(ex._data_pool["rows"]) == {"c0", "c1", "c2"}
@@ -352,7 +352,7 @@ def test_data_pool_bounded_lru_eviction():
     assert ex._pool.stats["evictions"] == 2
     # evicted client readmits via the recompute path, bit-identically
     warm = ex.run_cohort_stacked(clients[:2], params, round_id=1)
-    cold = BatchedExecutor(model).run_cohort_stacked(clients[:2], params,
+    cold = BatchedExecutor(model, max_clients=64).run_cohort_stacked(clients[:2], params,
                                                      round_id=1)
     for a, b in zip(jax.tree_util.tree_leaves(warm["updates"]),
                     jax.tree_util.tree_leaves(cold["updates"])):

@@ -58,10 +58,10 @@ _SUBPROC = textwrap.dedent("""
                   jax.tree_util.tree_leaves(st["updates"])]
         return leaves, st["loss"], st["acc"]
 
-    base_leaves, base_loss, base_acc = stacked_result(BatchedExecutor(model))
+    base_leaves, base_loss, base_acc = stacked_result(BatchedExecutor(model, max_clients=64))
 
     for k in (1, 2, 4, 8):
-        ex = BatchedExecutor(model, distributed="data",
+        ex = BatchedExecutor(model, max_clients=64, distributed="data",
                              devices=jax.devices()[:k])
         assert ex.mesh.size == k
         leaves, loss, acc = stacked_result(ex)
@@ -136,7 +136,8 @@ def test_distributed_needs_devices():
     from repro.models.small import linear_model
 
     with pytest.raises(ValueError, match="no.*devices|devices.*none|at least one"):
-        BatchedExecutor(linear_model(), distributed="data", devices=[])
+        BatchedExecutor(linear_model(), max_clients=64, distributed="data",
+                        devices=[])
 
 
 def test_bad_distributed_value_rejected():

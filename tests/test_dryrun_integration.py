@@ -47,3 +47,21 @@ def test_dryrun_multi_pod_fed_round(tmp_path):
     assert rec["mesh_shape"] == [2, 16, 16]
     assert rec["step"] == "fed"
     assert rec["roofline"]["collective_bytes"] > 0   # the pod-sync collective
+
+
+def test_dryrun_failure_exits_nonzero(tmp_path):
+    """A dry run that raises prints [FAIL] and the launcher exits non-zero
+    (a caught failure must not look like success to a caller)."""
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    # a negative sequence length cannot be lowered for any arch
+    r = subprocess.run(
+        [sys.executable, "-m", "repro.launch.dryrun", "--out", str(tmp_path),
+         "--arch", "rwkv6-1.6b", "--shape", "train_4k",
+         "--seq-override", "-8"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
+    assert "[FAIL] rwkv6-1.6b train_4k" in r.stdout
+    assert r.returncode != 0
+    assert "1 dry run(s) failed" in r.stderr
+    assert os.listdir(tmp_path) == []

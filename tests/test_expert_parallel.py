@@ -27,10 +27,10 @@ _EP_SUBPROC = textwrap.dedent("""
                      moe=MoEConfig(n_experts=8, top_k=2, d_expert=64))
     p = init_params(moe_mod.moe_defs(cfg), jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32))
-    from repro.models.sharding import make_mesh, use_mesh
+    from repro.models.sharding import make_mesh
     mesh = make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
     ref, _ = moe_mod.moe_ffn(cfg, p, x, capacity_factor=8.0)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         out, aux = jax.jit(
             lambda p, x: moe_mod.moe_ffn_expert_parallel(cfg, p, x, 8.0))(p, x)
         g = jax.jit(jax.grad(

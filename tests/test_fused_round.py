@@ -343,3 +343,40 @@ def test_fused_matches_staged_on_8_device_mesh():
                        capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stderr[-3000:]
     assert "MESH_FUSED_OK" in r.stdout
+
+
+@pytest.mark.slow
+def test_fused_round_traces_once_with_stc_on_mesh():
+    """Over rounds that keep bringing in new clients, the sharded fused STC
+    round compiles once: the error-feedback store is sized up front to
+    the clients the run can touch, and the first round's params are
+    placed on the mesh the way the program returns them."""
+    code = textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import sys
+        sys.path.insert(0, "src")
+        import repro as easyfl
+        from repro.core import batched
+
+        for distributed in ("none", "data"):
+            easyfl.reset()
+            easyfl.init({
+                "model": "linear", "dataset": "synthetic",
+                "data": {"num_clients": 100, "batch_size": 32},
+                "server": {"rounds": 3, "clients_per_round": 16,
+                           "test_every": 0},
+                "client": {"local_epochs": 1, "compression": "stc"},
+                "resources": {"execution": "batched",
+                              "distributed": distributed},
+            })
+            t0 = batched.round_trace_count()
+            easyfl.run()
+            easyfl.reset()
+            assert batched.round_trace_count() - t0 == 1, distributed
+        print("TRACED_ONCE")
+    """)
+    r = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "TRACED_ONCE" in r.stdout

@@ -115,6 +115,18 @@ def test_store_state_roundtrip_is_tier_agnostic():
             np.testing.assert_array_equal(x, y)
 
 
+def test_set_rows_matches_scatter_and_drops_out_of_range():
+    from repro.core.tiered_store import set_rows
+
+    rng = np.random.RandomState(0)
+    m = rng.randn(6, 3, 2).astype(np.float32)
+    vals = rng.randn(4, 3, 2).astype(np.float32)
+    rows = np.array([4, 0, 6, 2], np.int32)        # 6 is out of range
+    want = m.copy()
+    want[[4, 0, 2]] = vals[[0, 1, 3]]
+    np.testing.assert_array_equal(np.asarray(set_rows(m, rows, vals)), want)
+
+
 def test_store_rejects_bad_args():
     with pytest.raises(ValueError, match="spill"):
         TieredRowStore(4, spill="nope")

@@ -361,77 +361,82 @@ def _server_step_fn(method: str, stc_sparsity: float, use_faults: bool,
         leaves, treedef = jax.tree_util.tree_flatten(updates)
         nb = leaves[0].shape[0]
         flat_leaves, nnz_list, new_ef = [], [], []
-        for li, leaf in enumerate(leaves):
-            size = int(np.prod(leaf.shape[1:], dtype=np.int64))
-            flat = leaf.reshape(nb, size).astype(jnp.float32)
-            if method != "none":
-                # error-correct by the stored residual; padded clients
-                # (row sentinel = alloc) gather 0 / write nowhere, so
-                # semantics match the staged compress_stacked exactly
-                res = jnp.take(ef_leaves[li], ef_rows, axis=0,
-                               mode="fill", fill_value=0.0)
-                corrected = flat + res
-                if size < DENSE_MIN_ELEMS:   # tiny tensors stay dense
-                    sent = corrected
-                elif method == "stc":
-                    sent, nnz = kops.stc_compress_batched(
-                        corrected, stc_sparsity, interpret=interpret,
-                        mesh=mesh)
-                    nnz_list.append(nnz)
-                else:
-                    sent, _ = kops.int8_roundtrip_batched(
-                        corrected, interpret=interpret, mesh=mesh)
-                new_ef.append(set_rows(ef_leaves[li], ef_rows,
-                                       corrected - sent))
-                flat = sent
-            flat_leaves.append(flat)
-        flat = (flat_leaves[0] if len(flat_leaves) == 1
-                else jnp.concatenate(flat_leaves, axis=1))
+        with jax.named_scope("fl.compress"):
+            for li, leaf in enumerate(leaves):
+                size = int(np.prod(leaf.shape[1:], dtype=np.int64))
+                flat = leaf.reshape(nb, size).astype(jnp.float32)
+                if method != "none":
+                    # error-correct by the stored residual; padded clients
+                    # (row sentinel = alloc) gather 0 / write nowhere, so
+                    # semantics match the staged compress_stacked exactly
+                    res = jnp.take(ef_leaves[li], ef_rows, axis=0,
+                                   mode="fill", fill_value=0.0)
+                    corrected = flat + res
+                    if size < DENSE_MIN_ELEMS:   # tiny tensors stay dense
+                        sent = corrected
+                    elif method == "stc":
+                        sent, nnz = kops.stc_compress_batched(
+                            corrected, stc_sparsity, interpret=interpret,
+                            mesh=mesh)
+                        nnz_list.append(nnz)
+                    else:
+                        sent, _ = kops.int8_roundtrip_batched(
+                            corrected, interpret=interpret, mesh=mesh)
+                    new_ef.append(set_rows(ef_leaves[li], ef_rows,
+                                           corrected - sent))
+                    flat = sent
+                flat_leaves.append(flat)
+            flat = (flat_leaves[0] if len(flat_leaves) == 1
+                    else jnp.concatenate(flat_leaves, axis=1))
 
-        if use_faults:
-            # identical op order to aggregate_stacked's fault branch:
-            # poison AFTER compression, guard on the sent values, zero
-            # rejected rows in the data, renormalize the survivors
-            flat = jnp.where(nan_mask[:, None], jnp.float32(jnp.nan), flat)
-            wj = weights * mask
-            ok = jnp.isfinite(flat).all(axis=1)
-            if max_update_norm > 0:
-                norms = jnp.sqrt(jnp.sum(
-                    jnp.square(flat.astype(jnp.float32)), axis=1))
-                ok = ok & (norms <= max_update_norm)
-            wj = wj * ok.astype(jnp.float32)
-            flat = jnp.where(ok[:, None], flat, 0.0)
-            wsum = jnp.sum(wj)
-            w = jnp.where(wsum > 0, wj / wsum, 0.0)
-        else:
-            ok = jnp.ones((nb,), bool)
-            w = weights
+        with jax.named_scope("fl.aggregate"):
+            if use_faults:
+                # identical op order to aggregate_stacked's fault branch:
+                # poison AFTER compression, guard on the sent values, zero
+                # rejected rows in the data, renormalize the survivors
+                flat = jnp.where(nan_mask[:, None], jnp.float32(jnp.nan),
+                                 flat)
+                wj = weights * mask
+                ok = jnp.isfinite(flat).all(axis=1)
+                if max_update_norm > 0:
+                    norms = jnp.sqrt(jnp.sum(
+                        jnp.square(flat.astype(jnp.float32)), axis=1))
+                    ok = ok & (norms <= max_update_norm)
+                wj = wj * ok.astype(jnp.float32)
+                flat = jnp.where(ok[:, None], flat, 0.0)
+                wsum = jnp.sum(wj)
+                w = jnp.where(wsum > 0, wj / wsum, 0.0)
+            else:
+                ok = jnp.ones((nb,), bool)
+                w = weights
 
-        if mesh is not None:
-            delta = fedavg_aggregate_sharded(
-                flat, w, mesh, interpret=interpret,
-                fanout=(fanout or int(np.ceil(np.sqrt(nb)))) if tree else 0)
-        elif tree:
-            delta = fedavg_aggregate_tree(
-                flat, w, fanout=fanout, use_kernel=use_kernel,
-                interpret=interpret)
-        elif use_kernel:
-            delta = kops.fedavg_aggregate(flat, w, interpret=interpret)
-        else:
-            delta = jnp.einsum("n,nd->d", w, flat.astype(jnp.float32),
-                               precision=HIGHEST)
+            if mesh is not None:
+                delta = fedavg_aggregate_sharded(
+                    flat, w, mesh, interpret=interpret,
+                    fanout=((fanout or int(np.ceil(np.sqrt(nb)))) if tree
+                            else 0))
+            elif tree:
+                delta = fedavg_aggregate_tree(
+                    flat, w, fanout=fanout, use_kernel=use_kernel,
+                    interpret=interpret)
+            elif use_kernel:
+                delta = kops.fedavg_aggregate(flat, w, interpret=interpret)
+            else:
+                delta = jnp.einsum("n,nd->d", w, flat.astype(jnp.float32),
+                                   precision=HIGHEST)
 
-        out, off = [], 0
-        for leaf in leaves:
-            size = int(np.prod(leaf.shape[1:], dtype=np.int64))
-            out.append(delta[off: off + size].reshape(leaf.shape[1:]))
-            off += size
-        delta_tree = jax.tree_util.tree_unflatten(treedef, out)
+            out, off = [], 0
+            for leaf in leaves:
+                size = int(np.prod(leaf.shape[1:], dtype=np.int64))
+                out.append(delta[off: off + size].reshape(leaf.shape[1:]))
+                off += size
+            delta_tree = jax.tree_util.tree_unflatten(treedef, out)
         # the server apply (aggregation.apply_delta), in-program
-        new_global = jax.tree_util.tree_map(
-            lambda p, d: (p.astype(jnp.float32)
-                          + server_lr * d).astype(p.dtype),
-            global_params, delta_tree)
+        with jax.named_scope("fl.apply"):
+            new_global = jax.tree_util.tree_map(
+                lambda p, d: (p.astype(jnp.float32)
+                              + server_lr * d).astype(p.dtype),
+                global_params, delta_tree)
         return new_global, ok, tuple(nnz_list), tuple(new_ef)
 
     return server_step
@@ -494,15 +499,16 @@ def make_round_program(model: FLModel, optimizer: TracedOptimizer,
         global _round_traces
         _round_traces += 1           # executes once per jit trace/compile
         nb = x.shape[0]
-        stacked = jax.tree_util.tree_map(
-            lambda p: jnp.broadcast_to(p[None], (nb,) + p.shape),
-            global_params)
-        if mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            stacked = jax.lax.with_sharding_constraint(
-                stacked, NamedSharding(mesh, P(CLIENT_AXIS)))
-        updates, loss, acc = batched(stacked, x, y, idx, n_steps, vec,
-                                     global_params)
+        with jax.named_scope("fl.train"):
+            stacked = jax.tree_util.tree_map(
+                lambda p: jnp.broadcast_to(p[None], (nb,) + p.shape),
+                global_params)
+            if mesh is not None:
+                from jax.sharding import NamedSharding, PartitionSpec as P
+                stacked = jax.lax.with_sharding_constraint(
+                    stacked, NamedSharding(mesh, P(CLIENT_AXIS)))
+            updates, loss, acc = batched(stacked, x, y, idx, n_steps, vec,
+                                         global_params)
         new_global, ok, nnz, new_ef = server_step(
             global_params, updates, weights, mask, nan_mask, ef_leaves,
             ef_rows)
@@ -826,35 +832,40 @@ class BatchedExecutor:
         distributed aggregation fast path consumes this directly so client
         updates never gather onto one device.
         """
-        Nb, S, vec, optimizer, xd, yd, idx, n_steps = self._cohort_inputs(
-            clients, round_id)
+        with jax.profiler.TraceAnnotation("fl.inputs", round=round_id,
+                                          clients=len(clients)) as span:
+            Nb, S, vec, optimizer, xd, yd, idx, n_steps = self._cohort_inputs(
+                clients, round_id)
+            span.set_metadata(bucket=Nb, steps=S)
+            program = make_cohort_program(
+                self.model, optimizer, S,
+                use_prox=bool((vec.mu > 0).any()),
+                use_clip=bool((vec.max_norm > 0).any()),
+                mesh=self.mesh)
 
-        program = make_cohort_program(
-            self.model, optimizer, S,
-            use_prox=bool((vec.mu > 0).any()),
-            use_clip=bool((vec.max_norm > 0).any()),
-            mesh=self.mesh)
-
-        stacked = jax.tree_util.tree_map(
-            lambda p: jnp.broadcast_to(p[None], (Nb,) + p.shape), global_params)
-        if self.mesh is not None:
-            # eager broadcast_to commits to the default device; place the
-            # donated buffer on its client-dim sharding explicitly
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            stacked = jax.device_put(
-                stacked, NamedSharding(self.mesh, P(CLIENT_AXIS)))
+            stacked = jax.tree_util.tree_map(
+                lambda p: jnp.broadcast_to(p[None], (Nb,) + p.shape),
+                global_params)
+            if self.mesh is not None:
+                # eager broadcast_to commits to the default device; place the
+                # donated buffer on its client-dim sharding explicitly
+                from jax.sharding import NamedSharding, PartitionSpec as P
+                stacked = jax.device_put(
+                    stacked, NamedSharding(self.mesh, P(CLIENT_AXIS)))
         t0 = time.perf_counter()
         with warnings.catch_warnings():
             # CPU backends may decline the donation; that is fine.
             warnings.filterwarnings("ignore", message=".*donated.*")
-            updates, loss, acc = program(
-                stacked, xd, yd, jnp.asarray(idx),
-                jnp.asarray(n_steps),
-                jax.tree_util.tree_map(jnp.asarray, vec), global_params)
+            with jax.profiler.TraceAnnotation("fl.dispatch", round=round_id):
+                updates, loss, acc = program(
+                    stacked, xd, yd, jnp.asarray(idx),
+                    jnp.asarray(n_steps),
+                    jax.tree_util.tree_map(jnp.asarray, vec), global_params)
         _note_dispatch()
         # the round's timing boundary: ``wall`` feeds the virtual clock, so
         # the program must actually have finished here
-        jax.block_until_ready(updates)  # flcheck: ignore[FLC101]  -- intended timing boundary
+        with jax.profiler.TraceAnnotation("fl.fetch", round=round_id):
+            jax.block_until_ready(updates)  # flcheck: ignore[FLC101]  -- intended timing boundary
         _note_host_sync()
         wall = time.perf_counter() - t0
 
@@ -895,72 +906,77 @@ class BatchedExecutor:
         ``global_params`` are donated, so callers must drop old references
         to the incoming server params.
         """
-        Nb, S, vec, optimizer, xd, yd, idx, n_steps = self._cohort_inputs(
-            clients, round_id)
-        from repro.core.aggregation import fedavg_weights
-        from repro.kernels import ops as kops
+        with jax.profiler.TraceAnnotation("fl.inputs", round=round_id,
+                                          clients=len(clients)) as span:
+            Nb, S, vec, optimizer, xd, yd, idx, n_steps = self._cohort_inputs(
+                clients, round_id)
+            span.set_metadata(bucket=Nb, steps=S)
+            from repro.core.aggregation import fedavg_weights
+            from repro.kernels import ops as kops
 
-        N = len(clients)
-        num_samples = np.asarray([len(c.data) for c in clients],
-                                 dtype=np.int64)
-        w = np.zeros((Nb,), np.float32)
-        w[:N] = fedavg_weights(num_samples)
-        m = np.zeros((Nb,), np.float32)
-        m[:N] = 1.0 if mask is None else np.asarray(mask, np.float32)
-        nanm = np.zeros((Nb,), bool)
-        if len(nan_rows):
-            nanm[np.asarray(nan_rows, np.int64)] = True
+            N = len(clients)
+            num_samples = np.asarray([len(c.data) for c in clients],
+                                     dtype=np.int64)
+            w = np.zeros((Nb,), np.float32)
+            w[:N] = fedavg_weights(num_samples)
+            m = np.zeros((Nb,), np.float32)
+            m[:N] = 1.0 if mask is None else np.asarray(mask, np.float32)
+            nanm = np.zeros((Nb,), bool)
+            if len(nan_rows):
+                nanm[np.asarray(nan_rows, np.int64)] = True
 
-        sizes = [int(np.prod(l.shape, dtype=np.int64))
-                 for l in jax.tree_util.tree_leaves(global_params)]
-        if method != "none":
-            if self._ef is None:
-                self._ef = self._new_ef_store()
-            if self._ef.leaves and \
-                    [l.shape[1] for l in self._ef.leaves] != sizes:
-                raise ValueError(
-                    "error-feedback store leaf sizes "
-                    f"{[l.shape[1] for l in self._ef.leaves]} do not match "
-                    f"the update structure {sizes}; one executor serves one "
-                    f"model")
-            rows = self._ef.ensure(
-                [c.client_id for c in clients],
-                lambda cid: [np.zeros((s,), np.float32) for s in sizes])
-            ef_leaves = tuple(self._ef.leaves)
-            # out-of-bounds sentinel: padded clients gather 0 residual
-            # (mode="fill") and their scatter rows are dropped
-            ef_rows = np.full((Nb,), self._ef.alloc, np.int32)
-            ef_rows[:N] = rows
-        else:
-            ef_leaves, ef_rows = (), np.zeros((Nb,), np.int32)
+            sizes = [int(np.prod(l.shape, dtype=np.int64))
+                     for l in jax.tree_util.tree_leaves(global_params)]
+            if method != "none":
+                if self._ef is None:
+                    self._ef = self._new_ef_store()
+                if self._ef.leaves and \
+                        [l.shape[1] for l in self._ef.leaves] != sizes:
+                    raise ValueError(
+                        "error-feedback store leaf sizes "
+                        f"{[l.shape[1] for l in self._ef.leaves]} do not "
+                        f"match the update structure {sizes}; one executor "
+                        f"serves one model")
+                rows = self._ef.ensure(
+                    [c.client_id for c in clients],
+                    lambda cid: [np.zeros((s,), np.float32) for s in sizes])
+                ef_leaves = tuple(self._ef.leaves)
+                # out-of-bounds sentinel: padded clients gather 0 residual
+                # (mode="fill") and their scatter rows are dropped
+                ef_rows = np.full((Nb,), self._ef.alloc, np.int32)
+                ef_rows[:N] = rows
+            else:
+                ef_leaves, ef_rows = (), np.zeros((Nb,), np.int32)
 
-        program = make_round_program(
-            self.model, optimizer, S,
-            use_prox=bool((vec.mu > 0).any()),
-            use_clip=bool((vec.max_norm > 0).any()),
-            method=method, stc_sparsity=float(stc_sparsity),
-            use_faults=use_faults, max_update_norm=float(max_update_norm),
-            topology=topology, fanout=int(fanout), use_kernel=use_kernel,
-            server_lr=float(server_lr),
-            interpret=interpret, mesh=self.mesh)
-        if self.mesh is not None:
-            # the program returns params replicated over the mesh; place the
-            # first round's params the same way, or round 2 would retrace on
-            # the changed input sharding (a no-op once they are placed)
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            global_params = jax.device_put(global_params,
-                                           NamedSharding(self.mesh, P()))
+            program = make_round_program(
+                self.model, optimizer, S,
+                use_prox=bool((vec.mu > 0).any()),
+                use_clip=bool((vec.max_norm > 0).any()),
+                method=method, stc_sparsity=float(stc_sparsity),
+                use_faults=use_faults, max_update_norm=float(max_update_norm),
+                topology=topology, fanout=int(fanout), use_kernel=use_kernel,
+                server_lr=float(server_lr),
+                interpret=interpret, mesh=self.mesh)
+            if self.mesh is not None:
+                # the program returns params replicated over the mesh; place
+                # the first round's params the same way, or round 2 would
+                # retrace on the changed input sharding (a no-op once they
+                # are placed)
+                from jax.sharding import NamedSharding, PartitionSpec as P
+                global_params = jax.device_put(global_params,
+                                               NamedSharding(self.mesh, P()))
 
         t0 = time.perf_counter()
         with warnings.catch_warnings():
             # CPU backends may decline the donation; that is fine.
             warnings.filterwarnings("ignore", message=".*donated.*")
-            new_global, loss, acc, ok, nnz, new_ef = program(
-                global_params, xd, yd, jnp.asarray(idx),
-                jnp.asarray(n_steps),
-                jax.tree_util.tree_map(jnp.asarray, vec),
-                jnp.asarray(w), jnp.asarray(m), jnp.asarray(nanm),
-                ef_leaves, jnp.asarray(ef_rows))
+            with jax.profiler.TraceAnnotation("fl.dispatch", round=round_id):
+                new_global, loss, acc, ok, nnz, new_ef = program(
+                    global_params, xd, yd, jnp.asarray(idx),
+                    jnp.asarray(n_steps),
+                    jax.tree_util.tree_map(jnp.asarray, vec),
+                    jnp.asarray(w), jnp.asarray(m), jnp.asarray(nanm),
+                    ef_leaves, jnp.asarray(ef_rows))
         _note_dispatch()
         if method != "none":
             self._ef.leaves = list(new_ef)
@@ -982,7 +998,8 @@ class BatchedExecutor:
 
         def fetch():
             # the round's ONE batched device->host transfer
-            l_h, a_h, ok_h, nnz_h = jax.device_get((loss, acc, ok, nnz))  # flcheck: ignore[FLC101]  -- the fused round's single batched fetch
+            with jax.profiler.TraceAnnotation("fl.fetch", round=round_id):
+                l_h, a_h, ok_h, nnz_h = jax.device_get((loss, acc, ok, nnz))  # flcheck: ignore[FLC101]  -- the fused round's single batched fetch
             _note_host_sync()
             st["loss"], st["acc"] = np.asarray(l_h), np.asarray(a_h)
             if use_faults:

@@ -270,10 +270,27 @@ class Trainer:
         return t
 
     # ------------------------------------------------------------------
+    def _batched_cohort(self, selected: List[str], payload: Dict[str, Any]):
+        """The selected clients (each built, and its data drawn, on first
+        use) and the global params they all train from.  The pre-train
+        stages run once, through the first client; per-client pre-train
+        or ``train`` overrides cannot be vectorized and raise."""
+        clients = [self.client(c) for c in selected]
+        for stage in ("download", "decompression", "train"):
+            impls = {getattr(type(c), stage) for c in clients}
+            if len(impls) > 1 or (stage == "train"
+                                  and impls != {Client.train}):
+                raise ValueError(
+                    f"batched execution cannot vectorize per-client "
+                    f"{stage!r} overrides ({[type(c).__name__ for c in clients]}); "
+                    f"use resources.execution='sequential'")
+        return clients, clients[0].decompression(clients[0].download(payload))
+
     def _run_batched(self, selected: List[str], payload: Dict[str, Any],  # flcheck: hot
                      round_id: int,
                      plans: Optional[Dict[str, FaultPlan]] = None,
-                     counts: Optional[Dict[str, int]] = None):
+                     counts: Optional[Dict[str, int]] = None,
+                     cohort=None):
         """Train the whole cohort in one compiled program, then run each
         client's post-train stages (compression/encryption/upload) so
         strategy overrides like STC keep working.
@@ -284,10 +301,14 @@ class Trainer:
         ``train`` overrides cannot be vectorized and raise instead of
         silently diverging.
 
+        ``cohort`` is :meth:`_batched_cohort` of ``selected`` where the
+        caller has built it already (inside its ``fl.cohort`` span).
+
         Returns ``(results, aggregated, finish)``; ``finish`` is ``None``
-        except on deferred fused rounds (``tracking.round_sync=False``),
-        where the caller invokes it later to run the round's single
-        batched metric fetch and fill in ``metrics`` / ``payload_bytes``.
+        except on fused rounds, where the caller invokes it (inside its
+        ``fl.finalize`` span) to fill in ``metrics`` / ``payload_bytes``;
+        on a deferred fused round (``tracking.round_sync=False``) it first
+        runs the round's single batched metric fetch.
         With ``resources.round_fusion="auto"`` (default), an eligible
         synchronous round additionally fuses compression, fault
         weighting, aggregation AND the server apply into ONE dispatch
@@ -319,16 +340,9 @@ class Trainer:
         their per-client *sent* updates un-aggregated (``aggregated=False``)
         — the event loop buffers them for staleness-weighted FedBuff
         aggregation."""
-        clients = [self.client(c) for c in selected]
-        for stage in ("download", "decompression", "train"):
-            impls = {getattr(type(c), stage) for c in clients}
-            if len(impls) > 1 or (stage == "train"
-                                  and impls != {Client.train}):
-                raise ValueError(
-                    f"batched execution cannot vectorize per-client "
-                    f"{stage!r} overrides ({[type(c).__name__ for c in clients]}); "
-                    f"use resources.execution='sequential'")
-        global_params = clients[0].decompression(clients[0].download(payload))
+        clients, global_params = (
+            cohort if cohort is not None
+            else self._batched_cohort(selected, payload))
 
         method = self.cfg.client.compression
         default_post = all(
@@ -450,8 +464,7 @@ class Trainer:
                             res["_fault"] = lab
 
             if fetch is None:
-                complete()
-                return results, True, None
+                return results, True, complete
 
             def finish():
                 fetch()
@@ -581,7 +594,9 @@ class Trainer:
         — under ``tracking.round_sync=False`` — overlap round R's metric
         fetch with round R+1's dispatch; calling this method runs both
         back to back (the default, exact-clock behavior)."""
-        return self._dispatch_round(round_id)()
+        with jax.profiler.StepTraceAnnotation("fl.round", step_num=round_id,
+                                              round=round_id):
+            return self._dispatch_round(round_id)()
 
     def _dispatch_round(self, round_id: int  # flcheck: hot
                         ) -> Callable[[], Dict[str, float]]:
@@ -589,24 +604,27 @@ class Trainer:
             raise ValueError(
                 'resources.execution="async" replaces the synchronous round '
                 "loop with an event loop; call Trainer.run()")
-        server = self.server
-        f = self.cfg.faults
-        deadline = self.cfg.resources.round_deadline
-        selected = server.selection(self.fed_data.client_ids, round_id)
-        plans = counts = None
-        # a response deadline alone (faults off) still needs the
-        # degradation path: plans are all NO_FAULT, only misses zero-weight
-        if f.active or deadline > 0:
-            selected, plans, reselections = self._plan_cohort(selected,
-                                                              round_id)
-            counts = {"deadline_missed": 0, "rejected": 0,
-                      "reselections": reselections,
-                      "dropped": sum(p.dropout for p in plans.values()),
-                      "crashed": sum(p.crash for p in plans.values()),
-                      "straggled": sum(p.straggler
-                                       for p in plans.values())}
-        payload = server.distribution(selected)
-        groups = self._allocate(selected, round_id)
+        with jax.profiler.TraceAnnotation("fl.cohort", round=round_id):
+            server = self.server
+            f = self.cfg.faults
+            deadline = self.cfg.resources.round_deadline
+            selected = server.selection(self.fed_data.client_ids, round_id)
+            plans = counts = None
+            # a response deadline alone (faults off) still needs the
+            # degradation path: plans are all NO_FAULT, only misses zero-weight
+            if f.active or deadline > 0:
+                selected, plans, reselections = self._plan_cohort(selected,
+                                                                  round_id)
+                counts = {"deadline_missed": 0, "rejected": 0,
+                          "reselections": reselections,
+                          "dropped": sum(p.dropout for p in plans.values()),
+                          "crashed": sum(p.crash for p in plans.values()),
+                          "straggled": sum(p.straggler
+                                           for p in plans.values())}
+            payload = server.distribution(selected)
+            groups = self._allocate(selected, round_id)
+            cohort = (self._batched_cohort(selected, payload)
+                      if self.engine is not None else None)
 
         results, sim_times, wall_times = [], {}, {}
         aggregated, finish = False, None
@@ -614,7 +632,8 @@ class Trainer:
         down_bytes = payload.get("payload_bytes", 0) * len(selected)
         if self.engine is not None:
             results, aggregated, finish = self._run_batched(
-                selected, payload, round_id, plans=plans, counts=counts)
+                selected, payload, round_id, plans=plans, counts=counts,
+                cohort=cohort)
             for res in results:
                 cid = res["client_id"]
                 wall_times[cid] = res["train_time"]
@@ -690,61 +709,63 @@ class Trainer:
         params_r = server.params
 
         def finalize() -> Dict[str, float]:
-            if finish is not None:
-                finish()   # the deferred fused round's single batched fetch
-            survivors = [r for r in results if r.get("_fault") is None]
-            # one batched host sync for the whole cohort's wire accounting
-            # (compression.payload_bytes_many), instead of per-leaf
-            # blocking reads per client; crashed/dropped/deadline-missed
-            # uploads never reached the server, so their bytes don't count
-            arrived = (results if plans is None else
-                       [r for r in results
-                        if r.get("_fault") in (None, "rejected")])
-            up_bytes = sum(r["payload_bytes"] for r in arrived
-                           if "payload_bytes" in r)
-            missing = [r for r in arrived if "payload_bytes" not in r]
-            if missing:
-                up_bytes += sum(comp.payload_bytes_many(
-                    [r["update"] for r in missing]))
+            with jax.profiler.TraceAnnotation("fl.finalize", round=round_id):
+                if finish is not None:
+                    finish()   # fused: accounting (deferred: its fetch first)
+                survivors = [r for r in results if r.get("_fault") is None]
+                # one batched host sync for the whole cohort's wire accounting
+                # (compression.payload_bytes_many), instead of per-leaf
+                # blocking reads per client; crashed/dropped/deadline-missed
+                # uploads never reached the server, so their bytes don't count
+                arrived = (results if plans is None else
+                           [r for r in results
+                            if r.get("_fault") in (None, "rejected")])
+                up_bytes = sum(r["payload_bytes"] for r in arrived
+                               if "payload_bytes" in r)
+                missing = [r for r in arrived if "payload_bytes" not in r]
+                if missing:
+                    up_bytes += sum(comp.payload_bytes_many(
+                        [r["update"] for r in missing]))
 
-            train_loss = weighted_train_loss(
-                survivors if plans is not None else results) \
-                if plans is None or survivors else float("nan")
-            metrics = {
-                "round_time": round_virtual,
-                "wall_time": wall,
-                "clients": len(selected),
-                "comm_down_bytes": down_bytes,
-                "comm_up_bytes": up_bytes,
-                "train_loss": train_loss,
-            }
-            if plans is not None:
-                metrics.update(
-                    survivors=len(survivors),
-                    survivor_fraction=len(survivors) / max(len(selected), 1),
-                    **counts)
-            if self.cfg.server.test_every and \
-               (round_id + 1) % self.cfg.server.test_every == 0:
-                saved = server.params
-                server.params = params_r
-                try:
-                    metrics.update(server.test())
-                finally:
-                    server.params = saved
+                train_loss = weighted_train_loss(
+                    survivors if plans is not None else results) \
+                    if plans is None or survivors else float("nan")
+                metrics = {
+                    "round_time": round_virtual,
+                    "wall_time": wall,
+                    "clients": len(selected),
+                    "comm_down_bytes": down_bytes,
+                    "comm_up_bytes": up_bytes,
+                    "train_loss": train_loss,
+                }
+                if plans is not None:
+                    metrics.update(
+                        survivors=len(survivors),
+                        survivor_fraction=(len(survivors)
+                                           / max(len(selected), 1)),
+                        **counts)
+                if self.cfg.server.test_every and \
+                   (round_id + 1) % self.cfg.server.test_every == 0:
+                    saved = server.params
+                    server.params = params_r
+                    try:
+                        metrics.update(server.test())
+                    finally:
+                        server.params = saved
 
-            if self.cfg.tracking.enabled:
-                self.tracker.track_round(self.cfg.task_id, round_id,
-                                         **metrics)
-                for r in results:
-                    extra = ({} if r.get("_fault") is None
-                             else {"fault": r["_fault"]})
-                    self.tracker.track_client(
-                        self.cfg.task_id, round_id, r["client_id"],
-                        train_time=wall_times[r["client_id"]],
-                        simulated_time=sim_times[r["client_id"]],
-                        **r["metrics"], **extra)
-            self.history.append(metrics)
-            return metrics
+                if self.cfg.tracking.enabled:
+                    self.tracker.track_round(self.cfg.task_id, round_id,
+                                             **metrics)
+                    for r in results:
+                        extra = ({} if r.get("_fault") is None
+                                 else {"fault": r["_fault"]})
+                        self.tracker.track_client(
+                            self.cfg.task_id, round_id, r["client_id"],
+                            train_time=wall_times[r["client_id"]],
+                            simulated_time=sim_times[r["client_id"]],
+                            **r["metrics"], **extra)
+                self.history.append(metrics)
+                return metrics
 
         return finalize
 
@@ -767,28 +788,31 @@ class Trainer:
         :class:`FaultInjector`) and needs no persisted state."""
         from repro.checkpoint.store import save_checkpoint
 
-        state: Dict[str, Any] = {
-            "format": 1,
-            "round": int(completed),
-            "execution": self.cfg.resources.execution,
-            "finetune": self.cfg.client.finetune,
-            "server": self.server.state_dict(),
-            "history": self.history,
-            "het_assignment": dict(self.het.assignment),
-            "scheduler": {
-                "default_time": float(self.scheduler.default_time),
-                "profiles": {cid: [float(p.time), bool(p.profiled)]
-                             for cid, p in self.scheduler.profiles.items()},
-            },
-            "client_residuals": {
-                cid: jax.tree_util.tree_map(np.asarray, c._residual)
-                for cid, c in self.clients.items()
-                if c._residual is not None},
-        }
-        if self.engine is not None:
-            state["ef"] = self.engine.ef_state()
-        ck = self.cfg.checkpoint
-        return save_checkpoint(ck.dir, state, step=completed, keep=ck.keep)
+        with jax.profiler.TraceAnnotation("fl.checkpoint",
+                                          round=completed - 1):
+            state: Dict[str, Any] = {
+                "format": 1,
+                "round": int(completed),
+                "execution": self.cfg.resources.execution,
+                "finetune": self.cfg.client.finetune,
+                "server": self.server.state_dict(),
+                "history": self.history,
+                "het_assignment": dict(self.het.assignment),
+                "scheduler": {
+                    "default_time": float(self.scheduler.default_time),
+                    "profiles": {
+                        cid: [float(p.time), bool(p.profiled)]
+                        for cid, p in self.scheduler.profiles.items()},
+                },
+                "client_residuals": {
+                    cid: jax.tree_util.tree_map(np.asarray, c._residual)
+                    for cid, c in self.clients.items()
+                    if c._residual is not None},
+            }
+            if self.engine is not None:
+                state["ef"] = self.engine.ef_state()
+            ck = self.cfg.checkpoint
+            return save_checkpoint(ck.dir, state, step=completed, keep=ck.keep)
 
     def resume(self, callback: Optional[Callable] = None,
                step: Optional[int] = None) -> Dict[str, Any]:
@@ -870,21 +894,23 @@ class Trainer:
             ck = self.cfg.checkpoint
             te = self.cfg.server.test_every
             for r in range(start_round, self.cfg.server.rounds):
-                fin = self._dispatch_round(r)
-                if pending is not None:
-                    pending()
-                    pending = None
-                # checkpoint and test rounds must finalize before the next
-                # dispatch: the fused program donates its input params, so
-                # round R+1 consumes the buffers round R's deferred
-                # test()/save would otherwise read
-                eager = (ck.every and (r + 1) % ck.every == 0) or \
-                        (te and (r + 1) % te == 0)
-                if defer and not eager:
-                    pending = fin
-                else:
-                    fin()
-                    self._maybe_checkpoint(r + 1)
+                with jax.profiler.StepTraceAnnotation(
+                        "fl.round", step_num=r, round=r):
+                    fin = self._dispatch_round(r)
+                    if pending is not None:
+                        pending()
+                        pending = None
+                    # checkpoint and test rounds must finalize before the next
+                    # dispatch: the fused program donates its input params, so
+                    # round R+1 consumes the buffers round R's deferred
+                    # test()/save would otherwise read
+                    eager = (ck.every and (r + 1) % ck.every == 0) or \
+                            (te and (r + 1) % te == 0)
+                    if defer and not eager:
+                        pending = fin
+                    else:
+                        fin()
+                        self._maybe_checkpoint(r + 1)
             if pending is not None:
                 pending()
         self.server.finalize()

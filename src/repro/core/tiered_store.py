@@ -38,6 +38,7 @@ from __future__ import annotations
 from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -71,7 +72,8 @@ class TieredRowStore:
             caller's ``make_row`` recomputes on the next appearance.
         mesh: optional 1-D client mesh; device leaves are sharded along
             the row axis and allocation stays a multiple of ``mesh.size``.
-        name: label for error messages.
+        name: label for error messages, and the store's part of the
+            profiler span ``fl.<name>.insert`` that covers each insert.
     """
 
     def __init__(self, capacity: int, spill: str = "host", mesh=None,
@@ -85,6 +87,7 @@ class TieredRowStore:
         self.spill = spill
         self.mesh = mesh
         self.name = name
+        self._insert_span = f"fl.{name}.insert"
         self.leaves: List[Any] = []            # device (alloc, *shape)
         self.rows: Dict[str, int] = {}         # id -> hot-tier row
         self._lru: "OrderedDict[str, None]" = OrderedDict()
@@ -192,36 +195,38 @@ class TieredRowStore:
         pinned = set(ids)
         missing = [c for c in ids if c not in self.rows]
         if missing:
-            cap_eff = max(self.capacity, len(pinned))
-            values: List[List[np.ndarray]] = []
-            for cid in missing:
-                if cid in self._host:
-                    values.append(self._host.pop(cid))
-                    self.stats["reloads"] += 1
-                else:
-                    values.append([np.asarray(v) for v in make_row(cid)])
-                    self.stats["recomputes"] += 1
-            if not self.leaves:
+            with jax.profiler.TraceAnnotation(self._insert_span,
+                                              rows=len(missing)):
+                cap_eff = max(self.capacity, len(pinned))
+                values: List[List[np.ndarray]] = []
+                for cid in missing:
+                    if cid in self._host:
+                        values.append(self._host.pop(cid))
+                        self.stats["reloads"] += 1
+                    else:
+                        values.append([np.asarray(v) for v in make_row(cid)])
+                        self.stats["recomputes"] += 1
+                if not self.leaves:
+                    self.leaves = self._place([
+                        jnp.zeros((0,) + v.shape, v.dtype) for v in values[0]])
+                # keep resident <= cap_eff: evict LRU first (cap_eff >= the
+                # pinned count, so enough unpinned victims always exist),
+                # then grow the allocation toward the bound if still short
+                over = len(self.rows) + len(missing) - cap_eff
+                if over > 0:
+                    self._evict(over, pinned)
+                if len(missing) > len(self._free):
+                    self._grow(cap_eff)
+                slots = [self._free.pop() for _ in missing]
+                stacked = [np.stack([v[li] for v in values])
+                           for li in range(len(self.leaves))]
+                sl = np.asarray(slots, np.int32)
                 self.leaves = self._place([
-                    jnp.zeros((0,) + v.shape, v.dtype) for v in values[0]])
-            # keep resident <= cap_eff: evict LRU first (cap_eff >= the
-            # pinned count, so enough unpinned victims always exist),
-            # then grow the allocation toward the bound if still short
-            over = len(self.rows) + len(missing) - cap_eff
-            if over > 0:
-                self._evict(over, pinned)
-            if len(missing) > len(self._free):
-                self._grow(cap_eff)
-            slots = [self._free.pop() for _ in missing]
-            stacked = [np.stack([v[li] for v in values])
-                       for li in range(len(self.leaves))]
-            sl = np.asarray(slots, np.int32)
-            self.leaves = self._place([
-                set_rows(leaf, sl, vals)
-                for leaf, vals in zip(self.leaves, stacked)])
-            for cid, slot in zip(missing, slots):
-                self.rows[cid] = slot
-            self.stats["inserts"] += len(missing)
+                    set_rows(leaf, sl, vals)
+                    for leaf, vals in zip(self.leaves, stacked)])
+                for cid, slot in zip(missing, slots):
+                    self.rows[cid] = slot
+                self.stats["inserts"] += len(missing)
         for cid in ids:                # refresh recency, newest last
             self._lru.pop(cid, None)
             self._lru[cid] = None

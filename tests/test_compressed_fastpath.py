@@ -189,8 +189,9 @@ def test_fastpath_no_update_gather_payload_from_nnz():
     trainer = _make_trainer("stc")
     selected = trainer.server.selection(trainer.fed_data.client_ids, 0)
     payload = trainer.server.distribution(selected)
-    results, aggregated, _ = trainer._run_batched(selected, payload, 0)
+    results, aggregated, finish = trainer._run_batched(selected, payload, 0)
     assert aggregated is True
+    finish()                # the round's accounting, as its finalize runs it
     dense = sum(int(np.prod(l.shape)) * 4 for l in
                 jax.tree_util.tree_leaves(trainer.server.params))
     for res in results:

@@ -18,7 +18,7 @@ hand-written tests:
 
 The check uses a fixed tiny federation (4 clients, linear model) so it
 compiles in seconds; the contracts are about program *structure*, which
-the tiny shape already exercises (vmap+scan, donation, masking).
+the tiny shape already exercises (vmap + step loop, donation, masking).
 
 The same federation is then re-compiled with ``client.finetune = "lora"``
 semantics (``repro.models.lora.lora_wrap``, rank 2): the adapter-tree

@@ -6,8 +6,8 @@ with cohort size N — dominated by dispatch overhead at simulation scale.
 This engine stacks the selected clients' params / opt-states / cyclic-batch
 indices into leading-client-dim pytrees and runs all E local epochs of the
 whole cohort as **one** compiled program: ``jax.vmap`` over clients around a
-``jax.lax.scan`` over local steps (the FLGo-style vectorized multi-client
-simulation).
+``jax.lax.while_loop`` over local steps (the FLGo-style vectorized
+multi-client simulation).
 
 Under ``client.finetune = "lora"`` the cohort's stacked leaves are the
 low-rank adapter factors only — ``(N, d_in, r)`` / ``(N, r, d_out)``
@@ -23,9 +23,12 @@ Shape discipline (no per-round recompiles):
 * cohort size N, per-client step count S, and per-client sample count are
   each padded up to power-of-two *buckets*; the compile cache is keyed by
   ``(N_bucket, S_bucket, batch_shape)`` via the inner ``jax.jit``.
-* padded clients run 0 active steps and are discarded; padded steps are
-  masked out (params/opt-state frozen once ``step >= n_steps[client]``), so
-  results are bit-equivalent to running each client alone.
+* the step loop stops at the cohort's longest client (``max(n_steps)``,
+  traced, so the bucket S only sizes the batch-index input): bucket steps
+  past it are never run.  Below it a shorter client is masked (params /
+  opt-state frozen once ``step >= n_steps[client]``), and padded clients
+  run 0 active steps and are discarded, so results are bit-equivalent to
+  running each client alone.
 
 Per-client FedProx (``proximal_mu``), gradient clipping
 (``max_grad_norm``) and the full optimizer hyperparameter set ride along
@@ -135,6 +138,7 @@ _trace_count = 0
 _round_traces = 0
 _dispatches = 0
 _host_syncs = 0
+_skipped_steps = 0
 
 
 def cohort_trace_count() -> int:
@@ -171,6 +175,20 @@ def host_sync_count() -> int:
     Each ``block_until_ready`` / ``device_get`` the round pipeline performs
     bumps this once; the fused round performs exactly one batched fetch."""
     return _host_syncs
+
+
+def skipped_step_count() -> int:
+    """Bucket steps the local-training loop did not run this process.
+
+    Each dispatched cohort adds ``S - max(n_steps)``: its step bucket less
+    the longest real client, where the loop stops.  Flat when every
+    cohort's longest client fills its bucket."""
+    return _skipped_steps
+
+
+def _note_skipped_steps(n: int) -> None:
+    global _skipped_steps
+    _skipped_steps += n
 
 
 def _note_dispatch(n: int = 1) -> None:
@@ -229,21 +247,36 @@ def build_client_mesh(devices: Optional[Sequence] = None):
     return Mesh(np.asarray(devices[:n]), (CLIENT_AXIS,))
 
 
-def _one_client_fn(model: FLModel, optimizer: TracedOptimizer, steps: int,
-                   use_prox: bool, use_clip: bool):
-    """Single-client local-training body shared by the staged cohort
+def _cohort_train_fn(model: FLModel, optimizer: TracedOptimizer, steps: int,
+                     use_prox: bool, use_clip: bool):
+    """Local training of a stacked cohort, shared by the staged cohort
     program (:func:`make_cohort_program`) and the fused round program
     (:func:`make_round_program`), so both paths trace byte-identical
-    training arithmetic."""
+    training arithmetic.
 
-    def one_client(params, x, y, idx, n_steps, vec, global_params):
+        (params, x, y, idx, n_steps, vec, global_params)
+            -> (updates, loss_mean, acc_mean)
+
+    ``idx`` holds ``steps`` (the bucket S) batches per client, but the
+    step loop stops at the cohort's longest real client,
+    ``n_max = max(n_steps)``, read from the traced step counts: one
+    compiled program still serves every cohort of the bucket, and steps
+    past ``n_max`` cost nothing.  ``n_max`` enters the vmapped body
+    unbatched, so the loop stays one scalar ``while`` under ``vmap``."""
+
+    def one_client(params, x, y, idx, n_steps, n_max, vec, global_params):
         global _trace_count
         _trace_count += 1            # executes once per jit trace/compile
         opt_state = optimizer.init(params, vec.hp)
 
-        def body(carry, xs):
-            params, opt_state, loss_sum, acc_sum = carry
-            step, bidx = xs
+        def cond(carry):
+            # the bucket bound is redundant at run time (n_steps <= steps);
+            # it gives the loop a static trip count a cost model can read
+            return (carry[0] < n_max) & (carry[0] < steps)
+
+        def body(carry):
+            step, params, opt_state, loss_sum, acc_sum = carry
+            bidx = jax.lax.dynamic_index_in_dim(idx, step, keepdims=False)
             batch = {"x": x[bidx], "y": y[bidx]}
 
             def loss_fn(p):
@@ -269,7 +302,9 @@ def _one_client_fn(model: FLModel, optimizer: TracedOptimizer, steps: int,
                                                 vec.hp)
             new_params = apply_updates(params, updates)
 
-            active = step < n_steps          # padded steps leave state frozen
+            # a client shorter than the cohort's longest stays frozen once
+            # its own steps are done
+            active = step < n_steps
             params = jax.tree_util.tree_map(
                 lambda nw, od: jnp.where(active, nw, od), new_params, params)
             opt_state = jax.tree_util.tree_map(
@@ -277,26 +312,33 @@ def _one_client_fn(model: FLModel, optimizer: TracedOptimizer, steps: int,
             af = active.astype(jnp.float32)
             loss_sum = loss_sum + af * loss
             acc_sum = acc_sum + af * metrics.get("accuracy", jnp.float32(0))
-            return (params, opt_state, loss_sum, acc_sum), None
+            return step + 1, params, opt_state, loss_sum, acc_sum
 
-        (params, _, loss_sum, acc_sum), _ = jax.lax.scan(
-            body,
-            (params, opt_state, jnp.float32(0), jnp.float32(0)),
-            (jnp.arange(steps), idx))
+        _, params, _, loss_sum, acc_sum = jax.lax.while_loop(
+            cond, body,
+            (jnp.int32(0), params, opt_state, jnp.float32(0),
+             jnp.float32(0)))
         update = jax.tree_util.tree_map(
             lambda n, g: n.astype(jnp.float32) - g.astype(jnp.float32),
             params, global_params)
         denom = jnp.maximum(n_steps.astype(jnp.float32), 1.0)
         return update, loss_sum / denom, acc_sum / denom
 
-    return one_client
+    batched = jax.vmap(one_client, in_axes=(0, 0, 0, 0, 0, None, 0, None))
+
+    def train_cohort(params, x, y, idx, n_steps, vec, global_params):
+        n_max = jnp.max(n_steps)
+        return batched(params, x, y, idx, n_steps, n_max, vec, global_params)
+
+    return train_cohort
 
 
 @lru_cache(maxsize=32)
 def make_cohort_program(model: FLModel, optimizer: TracedOptimizer,
                         steps: int, use_prox: bool, use_clip: bool,
                         mesh=None):
-    """One jitted program running ``steps`` local steps for a whole cohort.
+    """One jitted program training a whole cohort: up to ``steps`` (the
+    step bucket) local steps, stopping at its longest client.
 
     Signature of the returned function (leading dim N_bucket everywhere
     except ``global_params``):
@@ -319,9 +361,7 @@ def make_cohort_program(model: FLModel, optimizer: TracedOptimizer,
     ``global_params`` is replicated, so the cohort streams through all
     devices; N_bucket must be a multiple of the mesh size.
     """
-    one_client = _one_client_fn(model, optimizer, steps, use_prox, use_clip)
-    batched = jax.vmap(one_client,
-                       in_axes=(0, 0, 0, 0, 0, 0, None))
+    batched = _cohort_train_fn(model, optimizer, steps, use_prox, use_clip)
     if mesh is None:
         return jax.jit(batched, donate_argnums=(0,))
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -454,7 +494,7 @@ def make_round_program(model: FLModel, optimizer: TracedOptimizer,
                        mesh=None):
     """ONE jitted program for the whole round (``resources.round_fusion``).
 
-    Fuses cohort training (the shared :func:`_one_client_fn` body —
+    Fuses cohort training (the shared :func:`_cohort_train_fn` body —
     byte-identical arithmetic to the staged path), in-program STC / int8
     compression with the error-feedback residual update, fault mask /
     NaN-guard / survivor renormalization, flat-or-hierarchical streaming
@@ -488,8 +528,7 @@ def make_round_program(model: FLModel, optimizer: TracedOptimizer,
     per-shard partial-sum + ``psum`` kernel — all inside the same
     program.
     """
-    one_client = _one_client_fn(model, optimizer, steps, use_prox, use_clip)
-    batched = jax.vmap(one_client, in_axes=(0, 0, 0, 0, 0, 0, None))
+    batched = _cohort_train_fn(model, optimizer, steps, use_prox, use_clip)
     server_step = _server_step_fn(method, stc_sparsity, use_faults,
                                   max_update_norm, topology, fanout,
                                   use_kernel, server_lr, interpret, mesh)
@@ -836,7 +875,8 @@ class BatchedExecutor:
                                           clients=len(clients)) as span:
             Nb, S, vec, optimizer, xd, yd, idx, n_steps = self._cohort_inputs(
                 clients, round_id)
-            span.set_metadata(bucket=Nb, steps=S)
+            loop_steps = int(n_steps.max())
+            span.set_metadata(bucket=Nb, steps=S, loop_steps=loop_steps)
             program = make_cohort_program(
                 self.model, optimizer, S,
                 use_prox=bool((vec.mu > 0).any()),
@@ -862,6 +902,7 @@ class BatchedExecutor:
                     jnp.asarray(n_steps),
                     jax.tree_util.tree_map(jnp.asarray, vec), global_params)
         _note_dispatch()
+        _note_skipped_steps(S - loop_steps)
         # the round's timing boundary: ``wall`` feeds the virtual clock, so
         # the program must actually have finished here
         with jax.profiler.TraceAnnotation("fl.fetch", round=round_id):
@@ -910,7 +951,8 @@ class BatchedExecutor:
                                           clients=len(clients)) as span:
             Nb, S, vec, optimizer, xd, yd, idx, n_steps = self._cohort_inputs(
                 clients, round_id)
-            span.set_metadata(bucket=Nb, steps=S)
+            loop_steps = int(n_steps.max())
+            span.set_metadata(bucket=Nb, steps=S, loop_steps=loop_steps)
             from repro.core.aggregation import fedavg_weights
             from repro.kernels import ops as kops
 
@@ -978,6 +1020,7 @@ class BatchedExecutor:
                     jnp.asarray(w), jnp.asarray(m), jnp.asarray(nanm),
                     ef_leaves, jnp.asarray(ef_rows))
         _note_dispatch()
+        _note_skipped_steps(S - loop_steps)
         if method != "none":
             self._ef.leaves = list(new_ef)
 

@@ -442,9 +442,9 @@ class ResourceConfig:
       immediately refilled with the *current* global model, and the server
       aggregates every buffer of ``buffer_size`` completions with
       staleness-discounted weights (``w_i ∝ n_i / (1+s_i)^staleness_power``).
-      Each dispatch wave runs through the batched vmap+scan executor as one
-      jitted micro-cohort, so waves of equal bucketed shape reuse one
-      compiled program.  Requires ``distributed="none"``.
+      Each dispatch wave runs through the batched vmap + step-loop
+      executor as one jitted micro-cohort, so waves of equal bucketed
+      shape reuse one compiled program.  Requires ``distributed="none"``.
 
     ``aggregation_kernel`` switches the FedAvg weighted average onto the
     chunked streaming Pallas kernel (``repro.kernels.fedavg_agg``); the

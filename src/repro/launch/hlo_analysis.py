@@ -150,8 +150,10 @@ def parse_hlo(hlo: str) -> Dict[str, Computation]:
     return comps
 
 
-def _trip_count(cond: Computation) -> int:
-    """Max integer constant in the loop condition (scan: iter < N)."""
+def _trip_count(cond: Computation, comps: Dict[str, Computation]) -> int:
+    """Max integer constant in the loop condition (scan: iter < N), its
+    fused callees included (a condition ``iter < n & iter < N`` with a
+    traced ``n`` compiles to one fusion holding N: its static bound)."""
     best = 1
     for ins in cond.instrs:
         if ins.op == "constant":
@@ -160,6 +162,9 @@ def _trip_count(cond: Computation) -> int:
                 best = max(best, int(m.group(1)))
         for m in re.finditer(r"constant\((\d+)\)", ins.args):
             best = max(best, int(m.group(1)))
+    for callee in cond.fusion_callees:
+        if callee in comps:
+            best = max(best, _trip_count(comps[callee], comps))
     return best
 
 
@@ -187,7 +192,7 @@ def _multipliers(comps: Dict[str, Computation]) -> Dict[str, float]:
             stack.append((callee, m))
         for cond_name, body_name, trips in comp.while_edges:
             if trips is None:
-                trips = (_trip_count(comps[cond_name])
+                trips = (_trip_count(comps[cond_name], comps)
                          if cond_name in comps else 1)
             stack.append((cond_name, m * (trips + 1)))
             stack.append((body_name, m * trips))
@@ -531,6 +536,6 @@ def analyze_hlo(hlo: str, pod_size: Optional[int] = None) -> HLOCost:
     for c in comps.values():
         for cond, body, trips in c.while_edges:
             if trips is None and cond in comps:
-                trips = _trip_count(comps[cond])
+                trips = _trip_count(comps[cond], comps)
             cost.while_trips[body] = trips or 1
     return cost

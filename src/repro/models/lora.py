@@ -19,7 +19,7 @@ whose parameter tree contains only the low-rank adapter factors:
   per client under ``vmap``).
 
 Because the wrapper *is* an ``FLModel``, every execution engine
-(sequential, batched vmap+scan, async) and every downstream stage
+(sequential, batched vmap + step loop, async) and every downstream stage
 (FedAvg aggregation, STC/int8 in-program compression, error-feedback
 residuals, checkpointing, ``comm_up_bytes`` accounting) operates on the
 adapter tree with zero changes — a cohort of N clients trains stacked

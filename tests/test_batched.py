@@ -419,3 +419,149 @@ def test_bucketing_pads_uneven_cohorts():
     assert bucket_pow2(5) == 8
     assert bucket_pow2(8) == 8
     assert bucket_pow2(100) == 128
+
+
+# ---------------------------------------------------------------------------
+# the local-training loop stops at the cohort's longest client
+# ---------------------------------------------------------------------------
+
+
+def _clients_of_steps(steps, batch_size=16, seed=0):
+    """One client per entry of ``steps``, each with a last partial batch,
+    so one local epoch takes exactly that many steps; SGD with momentum,
+    so a frozen client's optimizer state is exercised too."""
+    from repro.core.client import Client
+    from repro.core.config import ClientConfig
+    from repro.data.fed_data import ClientData
+    from repro.models.small import linear_model
+
+    model = linear_model()
+    rng = np.random.RandomState(seed)
+    cfg = ClientConfig(local_epochs=1, lr=0.1, momentum=0.9)
+    clients = []
+    for i, k in enumerate(steps):
+        n = batch_size * (k - 1) + 5
+        data = ClientData(rng.randn(n, 64).astype(np.float32),
+                          rng.randint(0, 10, n).astype(np.int32))
+        clients.append(Client(f"s{seed}_{i}", model, data, cfg,
+                              batch_size=batch_size))
+    return clients
+
+
+def _sequential(clients, params, round_id):
+    return [c.train(params, round_id=round_id) for c in clients]
+
+
+def _run_path(ex, path, clients, params, round_id):
+    """Loss and accuracy per client, plus the per-client updates (staged)
+    or the new global params (fused)."""
+    if path == "staged":
+        res = ex.run_cohort(clients, params, round_id=round_id)
+        return ([r["metrics"]["loss"] for r in res],
+                [r["metrics"]["accuracy"] for r in res],
+                [r["update"] for r in res])
+    # the fused program donates the params it is given: hand it a copy
+    st, new_global, _ = ex.run_round_fused(
+        clients, jax.tree_util.tree_map(jnp.copy, params), round_id)
+    n = len(clients)
+    return list(st["loss"][:n]), list(st["acc"][:n]), new_global
+
+
+@pytest.mark.parametrize("path", ["staged", "fused"])
+@pytest.mark.parametrize("steps", [(5, 5, 5), (3, 7)],
+                         ids=["below-bucket", "unbalanced"])
+def test_loop_to_longest_client_equals_sequential(path, steps):
+    """Real steps below the bucket S = 8 (5 of 8), and an unbalanced cohort
+    (3 and 7 of 8): the loop stops at the longest client, the shorter one
+    is masked below it, and each client matches ``Client.train``."""
+    from repro.core.aggregation import fedavg_weights
+    from repro.core.batched import BatchedExecutor
+
+    clients = _clients_of_steps(steps)
+    model = clients[0].model
+    params = model.init(jax.random.PRNGKey(0))
+    ex = BatchedExecutor(model, max_clients=64)
+    for r in range(2):
+        seq = _sequential(clients, params, r)
+        assert [s["metrics"]["batches"] for s in seq] == list(steps)
+        loss, acc, out = _run_path(ex, path, clients, params, r)
+        np.testing.assert_allclose(loss, [s["metrics"]["loss"] for s in seq],
+                                   rtol=1e-4)
+        np.testing.assert_allclose(
+            acc, [s["metrics"]["accuracy"] for s in seq], atol=1e-5)
+        if path == "staged":
+            pairs = [(jax.tree_util.tree_leaves(s["update"]),
+                      jax.tree_util.tree_leaves(u)) for s, u in zip(seq, out)]
+        else:
+            w = fedavg_weights([s["num_samples"] for s in seq])
+            want = jax.tree_util.tree_map(
+                lambda p, *us: np.asarray(p) + sum(
+                    wi * np.asarray(u) for wi, u in zip(w, us)),
+                params, *[s["update"] for s in seq])
+            pairs = [(jax.tree_util.tree_leaves(want),
+                      jax.tree_util.tree_leaves(out))]
+        for a_leaves, b_leaves in pairs:
+            for a, b in zip(a_leaves, b_leaves):
+                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                           rtol=1e-5, atol=1e-5)
+        if path == "fused":
+            params = out
+
+
+@pytest.mark.parametrize("path", ["staged", "fused"])
+def test_skipped_step_count_grows_by_bucket_less_longest_client(path):
+    from repro.core.batched import (BatchedExecutor, bucket_pow2,
+                                    skipped_step_count)
+
+    model = _clients_of_steps([1])[0].model
+    params = model.init(jax.random.PRNGKey(0))
+    ex = BatchedExecutor(model, max_clients=64)
+    for steps in [(5, 5, 5), (3, 7), (8, 2), (4,)]:
+        before = skipped_step_count()
+        _run_path(ex, path, _clients_of_steps(steps, seed=sum(steps)),
+                  params, 0)
+        assert skipped_step_count() - before == \
+            bucket_pow2(max(steps)) - max(steps), steps
+
+
+@pytest.mark.parametrize("path", ["staged", "fused"])
+def test_longest_client_changing_within_a_bucket_does_not_recompile(path):
+    """Two rounds at S = 8 whose longest clients take 5, then 7 steps: the
+    trip count is traced, so neither program traces again."""
+    from repro.core.batched import (BatchedExecutor, cohort_trace_count,
+                                    round_trace_count)
+
+    model = _clients_of_steps([1])[0].model
+    params = model.init(jax.random.PRNGKey(0))
+    ex = BatchedExecutor(model, max_clients=64)
+    _run_path(ex, path, _clients_of_steps((5, 5, 5), seed=1), params, 0)
+    before = (cohort_trace_count(), round_trace_count())
+    _run_path(ex, path, _clients_of_steps((7, 5, 3), seed=2), params, 1)
+    assert (cohort_trace_count(), round_trace_count()) == before
+
+
+@pytest.mark.parametrize("path", ["staged", "fused"])
+@pytest.mark.parametrize("steps", [(5, 5, 5), (3, 7), (8, 2)],
+                         ids=["below-bucket", "unbalanced", "full-bucket"])
+def test_loop_runs_the_longest_clients_steps_not_the_bucket(path, steps):
+    """The model's forward pass runs once per loop iteration (a host
+    callback counts them): ``max(n_steps)`` times, not the bucket's 8."""
+    import dataclasses
+
+    from repro.core.batched import BatchedExecutor
+
+    calls = []
+    clients = _clients_of_steps(steps)
+    base = clients[0].model
+
+    def apply(params, x):
+        jax.debug.callback(lambda: calls.append(1))
+        return base.apply(params, x)
+
+    model = dataclasses.replace(base, apply=apply)
+    for c in clients:
+        c.model = model
+    ex = BatchedExecutor(model, max_clients=64)
+    _run_path(ex, path, clients, model.init(jax.random.PRNGKey(0)), 0)
+    jax.effects_barrier()
+    assert len(calls) == max(steps)

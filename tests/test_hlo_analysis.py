@@ -40,6 +40,25 @@ def test_scan_multiplies_flops_by_trip_count():
     assert 12 in c.while_trips.values()
 
 
+def test_while_bounded_by_a_traced_count_and_a_constant_counts_the_constant():
+    """A loop ``i < n & i < 12`` with a traced ``n`` compiles its condition
+    to one fusion: its static bound, 12, is read from the fused callee."""
+    s = jax.ShapeDtypeStruct((128, 128), jnp.float32)
+    n = jax.ShapeDtypeStruct((), jnp.int32)
+
+    def f(a, b, n):
+        def body(c):
+            i, x = c
+            return i + 1, jnp.tanh(x @ b)
+        _, out = jax.lax.while_loop(lambda c: (c[0] < n) & (c[0] < 12),
+                                    body, (jnp.int32(0), a))
+        return out
+
+    c = analyze_hlo(_compile_text(f, s, s, n))
+    assert c.flops == pytest.approx(12 * 2 * 128**3, rel=0.05)
+    assert 12 in c.while_trips.values()
+
+
 def test_hbm_counts_matmul_traffic():
     s = jax.ShapeDtypeStruct((1024, 1024), jnp.float32)
     hlo = _compile_text(lambda a, b: a @ b, s, s)

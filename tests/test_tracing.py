@@ -16,6 +16,7 @@ import glob
 import os
 
 import jax
+import numpy as np
 import pytest
 
 import repro as easyfl
@@ -78,9 +79,10 @@ def _run_traced(trace_dir, cfg):
 
 @pytest.fixture(scope="module")
 def sync_run(tmp_path_factory):
-    """Three synchronous fused rounds under the profiler, and the shapes
-    of the round program's arguments as its first call saw them."""
-    programs = []
+    """Three synchronous fused rounds under the profiler, the shapes of the
+    round program's arguments as its first call saw them, and each call's
+    longest client (``max(n_steps)``)."""
+    programs, longest = [], []
     make = batched.make_round_program
 
     def recording(*args, **kwargs):
@@ -90,6 +92,7 @@ def sync_run(tmp_path_factory):
             if not programs:
                 programs.append((program, jax.tree_util.tree_map(
                     lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), a)))
+            longest.append(int(np.max(np.asarray(a[4]))))
             return program(*a)
         return call
 
@@ -98,11 +101,11 @@ def sync_run(tmp_path_factory):
         mp.setattr(batched, "make_round_program", recording)
         res = _run_traced(trace_dir, _config())
     assert len(res["history"]) == ROUNDS
-    return _spans(trace_dir), programs[0]
+    return _spans(trace_dir), programs[0], longest
 
 
 def test_each_phase_once_per_round_in_order_inside_its_round(sync_run):
-    spans, _ = sync_run
+    spans = sync_run[0]
     rounds = _named(spans, "fl.round")
     assert [s[3]["round"] for s in rounds] == list(range(ROUNDS))
     assert [s[3]["step_num"] for s in rounds] == list(range(ROUNDS))
@@ -118,23 +121,27 @@ def test_each_phase_once_per_round_in_order_inside_its_round(sync_run):
 
 
 def test_inputs_span_carries_the_cohort_shape(sync_run):
-    spans, _ = sync_run
-    for s in _named(spans, "fl.inputs"):
+    spans, _, longest = sync_run
+    inputs = _named(spans, "fl.inputs")
+    for s in inputs:
         # 6 clients bucket to 8; 2 epochs of cyclic batches to a power of 2
         assert s[3]["clients"] == CLIENTS and s[3]["bucket"] == 8
         assert s[3]["steps"] >= 2 and s[3]["steps"] & (s[3]["steps"] - 1) == 0
+        # the loop runs to the longest client, within the bucket
+        assert 2 <= s[3]["loop_steps"] <= s[3]["steps"]
+    assert [s[3]["loop_steps"] for s in inputs] == longest
 
 
 @pytest.mark.parametrize("store", ["fl.data-pool.insert", "fl.ef-store.insert"])
 def test_store_inserts_only_in_round_zero_inside_inputs(sync_run, store):
-    spans, _ = sync_run
+    spans = sync_run[0]
     inserts = _named(spans, store)
     assert len(inserts) == 1 and inserts[0][3]["rows"] == CLIENTS
     assert _inside(inserts[0], _named(spans, "fl.inputs")[0])
 
 
 def test_round_program_carries_the_four_stage_scopes(sync_run):
-    _, (program, shapes) = sync_run
+    program, shapes = sync_run[1]
     text = program.lower(*shapes).as_text(debug_info=True)
     for stage in STAGES:
         assert f"/{stage}/" in text, stage

@@ -7,7 +7,10 @@ would tempt a later change.  The faults are planted in the program itself:
 a round that leaves the global weights unchanged; a FedAvg that leaves
 out half of the cohort and takes the mean over the rest; FedAvg weights
 that sum to 0.7, a server step off by 30%; error feedback whose stored
-residuals are never added back.  Each has to come out as not correct.  The benchmark's own runs plant nothing.
+residuals are never added back; a program that freezes a base of its own
+drawing and not the one it was handed; FedAvg weights that ignore the
+clients' sample counts.  Each has to come out as not correct.  The
+benchmark's own runs plant nothing.
 """
 from __future__ import annotations
 
@@ -115,5 +118,54 @@ def no_residual():
         BatchedExecutor.run_round_fused = orig
 
 
+@contextlib.contextmanager
+def own_base():
+    """Under LoRA the program freezes the base that it draws itself from
+    ``PRNGKey(seed)``, as ``Trainer`` does for a model of its own, and not
+    the base it was handed."""
+    import jax
+    from repro.core.rounds import Trainer
+    from repro.models import lora
+    from repro.models.small import FLModel
+
+    orig_init, orig_wrap = Trainer.__init__, lora.lora_wrap
+
+    def init(self, config, model, *args, **kwargs):
+        def wrap(base_model, base, *wargs, **wkwargs):
+            drawn = FLModel.init(base_model, jax.random.PRNGKey(config.seed))
+            return orig_wrap(base_model, drawn, *wargs, **wkwargs)
+
+        lora.lora_wrap = wrap
+        try:
+            orig_init(self, config, model, *args, **kwargs)
+        finally:
+            lora.lora_wrap = orig_wrap
+
+    Trainer.__init__ = init
+    try:
+        yield
+    finally:
+        Trainer.__init__ = orig_init
+
+
+@contextlib.contextmanager
+def count_blind_weights():
+    """FedAvg weighs every client of the cohort equally, whatever its
+    sample count."""
+    from repro.core import aggregation
+
+    orig = aggregation.fedavg_weights
+
+    def weights(num_samples):
+        return np.full(len(num_samples), 1.0 / len(num_samples), np.float32)
+
+    aggregation.fedavg_weights = weights
+    try:
+        yield
+    finally:
+        aggregation.fedavg_weights = orig
+
+
 FAULTS = {"unchanged_state": unchanged_state, "half_cohort": half_cohort,
-          "scaled_weights": scaled_weights, "no_residual": no_residual}
+          "scaled_weights": scaled_weights, "no_residual": no_residual,
+          "own_base": own_base, "count_blind_weights": count_blind_weights}

@@ -2,17 +2,19 @@
 
     python bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-Set-up builds the system from the cell's files, makes its weights and data
-from the seed, and drives it through its first rounds: they compile every
-program the window uses, fill what the traffic fills, and are the rounds
-the reference follows.  Then ``Trainer.run_round`` is called back to back
-(closed loop) until ``--seconds`` have passed; the round in flight then
-finishes, and the window ends with it.  Afterwards the device's peak memory
-is read, the system is freed, and the plain reference replays the first
-rounds to decide ``correct``: as many as bring clients back to their
-stored residuals and data rows, and the data pool to its first evictions.  The last line of standard output is the
-result; the numbers compared, each with its limit, are the last lines of
-standard error and the last key of the result.
+Set-up builds the system from the cell's files, makes its weights (and any
+frozen base) and data from the seed, and drives it through its first
+rounds: they compile every program the window uses, fill what the traffic
+fills, and are the rounds the reference follows.  Then
+``Trainer.run_round`` is called back to back (closed loop) until
+``--seconds`` have passed; the round in flight then finishes, and the
+window ends with it.  Afterwards the device's peak memory is read, the
+system is freed, and the plain reference, which makes its own frozen base
+from the seed again, replays the first rounds to decide ``correct``: as
+many as bring clients back to their stored residuals and data rows, and
+the data pool to its first evictions.  The last line of standard output
+is the result; the numbers compared, each with its limit, are the last
+lines of standard error and the last key of the result.
 """
 from __future__ import annotations
 
@@ -126,8 +128,9 @@ def run_cell(bench: Bench, workload: str, seed: int, seconds: float, trace: bool
     compiles = compile_events.CompileEvents()
     fed = tr.Federation(traffic, config, seed)
     weights = refm.init_weights(ref, config, seed)
-    trainer = system.build(config, traffic, fed, weights, seed)
-    del weights
+    frozen = refm.init_frozen(ref, config, seed)
+    trainer = system.build(config, traffic, fed, weights, seed, frozen)
+    del weights, frozen
 
     # --- set-up: the first rounds, kept for the check; then until no round compiles
     check_rounds = int(limits["rounds"])
@@ -213,9 +216,12 @@ def run_cell(bench: Bench, workload: str, seed: int, seconds: float, trace: bool
 
     # --- the check: free the system, replay the first rounds in the reference
     del trainer, m
-    gc.collect()
     if not keep_programs:
-        jax.clear_caches()
+        kept = system.release()
+        if kept:
+            log(t_start, f"{kept} leaves of the frozen base outlived the program's caches; "
+                         f"deleted from the device")
+    gc.collect()
     log(t_start, f"window: {rounds} rounds in {window_s:.3f} s, {window_compiles} compiles; "
                  f"reference starts")
     want = (reference_of() if reference_of is not None else

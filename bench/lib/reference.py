@@ -15,6 +15,14 @@ configuration's own reference file, the weights from the seed, the data
 and cohorts from ``traffic``.  Precision ``"f32"`` runs every matmul and
 convolution at ``highest``; ``"bf16"`` is the control: the clients'
 training in bfloat16.
+
+The reference file gives ``init(key, cfg)`` and ``forward(params, x,
+cfg)``, and may give two more: ``loss(params, x, y, cfg, frozen)`` (the
+default is the cross-entropy of ``forward``'s logits against class labels
+``y``), and ``init_frozen(key, cfg)``, a tree that the clients use and do
+not train, such as the base model under low-rank adapters.  ``init`` then
+makes the trained tree only.  The frozen tree is made from the seed's own
+stream, and the clients differentiate the trained tree alone.
 """
 from __future__ import annotations
 
@@ -39,19 +47,43 @@ def init_weights(ref, cfg, seed):
     return jax.jit(lambda k: ref.init(k, cfg))(tr.jax_key(seed, tr.STREAM_WEIGHTS))
 
 
-def _loss(ref, cfg, params, x, y):
+def init_frozen(ref, cfg, seed):
+    """The frozen tree of a run, made on the device in one call, or None
+    where the configuration trains every weight."""
+    if not hasattr(ref, "init_frozen"):
+        return None
+    return jax.jit(lambda k: ref.init_frozen(k, cfg))(tr.jax_key(seed, tr.STREAM_FROZEN))
+
+
+def _loss(ref, cfg, params, x, y, frozen):
+    if hasattr(ref, "loss"):
+        return ref.loss(params, x, y, cfg, frozen)
     logits = ref.forward(params, x, cfg).astype(jnp.float32)
     logp = jax.nn.log_softmax(logits, axis=-1)
     return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=-1))
 
 
-def _local_train(ref, cfg, lr, momentum, dtype, params, x, y, idx):
-    p0 = jax.tree_util.tree_map(lambda a: a.astype(dtype), params)
-    x = x.astype(dtype)
+def _floats_as(dtype, tree):
+    return jax.tree_util.tree_map(
+        lambda a: a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating) else a, tree)
+
+
+def _narrowed(dtype, tree):
+    """``tree``'s float leaves wider than ``dtype`` cast to it, the rest as
+    stored: the control's clients compute in ``dtype``, and a base stored
+    narrower than the reference's precision is not copied wider."""
+    return jax.tree_util.tree_map(
+        lambda a: (a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating)
+                   and jnp.dtype(a.dtype).itemsize > jnp.dtype(dtype).itemsize else a), tree)
+
+
+def _local_train(ref, cfg, lr, momentum, dtype, params, frozen, x, y, idx):
+    p0 = _floats_as(dtype, params)
+    frozen, x = _narrowed(dtype, frozen), _floats_as(dtype, x)
 
     def step(carry, bidx):
         p, m = carry
-        loss, g = jax.value_and_grad(partial(_loss, ref, cfg))(p, x[bidx], y[bidx])
+        loss, g = jax.value_and_grad(partial(_loss, ref, cfg))(p, x[bidx], y[bidx], frozen)
         m = jax.tree_util.tree_map(lambda mi, gi: momentum * mi + gi, m, g)
         p = jax.tree_util.tree_map(lambda pi, mi: pi - lr * mi, p, m)
         return (p, m), loss
@@ -61,6 +93,15 @@ def _local_train(ref, cfg, lr, momentum, dtype, params, x, y, idx):
     update = jax.tree_util.tree_map(
         lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32), p, p0)
     return update, jnp.mean(losses)
+
+
+def _pad_rows(a: np.ndarray, rows: int) -> np.ndarray:
+    """``a`` with zero rows added up to ``rows``, which no batch reads:
+    clients of every size then share one compiled local step per count
+    of steps."""
+    if len(a) == rows:
+        return a
+    return np.concatenate([a, np.zeros((rows - len(a),) + a.shape[1:], a.dtype)])
 
 
 def stc_leaf(x, keep: float):
@@ -119,6 +160,8 @@ def run_reference(ref, cfg, fed: tr.Federation, traffic: Dict[str, Any], seed: i
         add = jax.jit(lambda acc, w, s: jax.tree_util.tree_map(
             lambda a, b: a + w * b, acc, s))
         params = init_weights(ref, cfg, seed)
+        frozen = init_frozen(ref, cfg, seed)
+        rows = fed.max_size()
         out: Dict[str, Any] = {"loss": [], "params": [jax.device_get(params)]}
         residual: Dict[int, Any] = {}
         zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
@@ -128,8 +171,9 @@ def run_reference(ref, cfg, fed: tr.Federation, traffic: Dict[str, Any], seed: i
             weights = sizes / sizes.sum()
             delta, loss = zeros, 0.0
             for w, i in zip(weights, cohort):
-                x, y = fed.data(i)
-                update, client_loss = local(params, x, y, tr.client_schedule(fed, i, r))
+                x, y = (_pad_rows(a, rows) for a in fed.data(i))
+                update, client_loss = local(params, frozen, x, y,
+                                             tr.client_schedule(fed, i, r))
                 if stc:
                     update, residual[i] = compress(update, residual.get(i, zeros))
                 delta = add(delta, jnp.float32(w), update)

@@ -2,12 +2,18 @@
 
 A cell is a workload entry: a configuration (``configs/<name>.json`` with its
 plain reference ``configs/<name>.py`` beside it), a traffic mix
-(``traffic/<name>.json``) and the limits of its correctness check
+(``traffic/<name>.json``, whose ``data.kind`` names a generator of client
+data in ``data/<kind>.py``) and the limits of its correctness check
 (``limits/<workload>.json``).  A per-layer metric is a reader in
 ``metrics/<name>.py`` (or of the quantity it names, :func:`quantity`); a
 count of operations or bytes is ``counts/<name>.py``.
-Adding a cell, a configuration or a metric adds files and entries here and
-edits none.
+
+A configuration says what is particular to it in its own files: the
+program's settings (the JSON file's ``"program"`` object), its loss and a
+frozen tree such as a base under LoRA (the reference's ``loss`` and
+``init_frozen``), and its FLOPs per trained sample (the count file's
+``train_flops``).  Adding a cell, a configuration or a metric adds files
+and entries here and edits none.
 """
 from __future__ import annotations
 

@@ -5,11 +5,18 @@ registered model, builds the server and a ``Trainer`` over the federated
 data, and calls ``Trainer.run``.  Here the same objects are built from the
 cell's files, with two things taken from the benchmark instead of the
 defaults: the cohort of every round (a server whose ``selection`` reads
-the traffic's draw) and the starting weights (made from the seed).  The
+the traffic's draw) and the starting weights (made from the seed).  Where
+the configuration has a frozen tree (the base under LoRA), that too is
+made from the seed and handed to the program.  A configuration's
+``"program"`` object is folded into the ``easyfl.init`` dictionary.  The
 timed entry is ``Trainer.run_round``.
 """
 from __future__ import annotations
 
+import functools
+import gc
+import sys
+import weakref
 from typing import Any, Dict, Iterator, List
 
 import jax
@@ -47,10 +54,20 @@ class LazyClients:
         return iter(self._ids)
 
 
+def _deep_merge(base: Dict[str, Any], extra: Dict[str, Any]) -> Dict[str, Any]:
+    """``base`` with ``extra`` folded in, object by object."""
+    out = dict(base)
+    for k, v in extra.items():
+        out[k] = (_deep_merge(out[k], v) if isinstance(v, dict) and isinstance(out.get(k), dict)
+                  else v)
+    return out
+
+
 def program_config(config: Dict[str, Any], traffic: Dict[str, Any], seed: int) -> Dict[str, Any]:
-    """The ``easyfl.init`` dictionary of a cell."""
+    """The ``easyfl.init`` dictionary of a cell: the traffic's settings,
+    then the configuration's ``"program"`` object folded in."""
     opt = traffic["optimizer"]
-    return {
+    return _deep_merge({
         "model": config["program_model"],
         "seed": int(seed) % 2**31,
         "data": {"batch_size": traffic["batch_size"]},
@@ -64,12 +81,46 @@ def program_config(config: Dict[str, Any], traffic: Dict[str, Any], seed: int) -
         "resources": {"execution": "batched",
                       "aggregation_kernel": bool(traffic["aggregation_kernel"]),
                       "distributed": traffic["distributed"]},
-    }
+    }, config.get("program", {}))
+
+
+def _tree_type(tree) -> Any:
+    return jax.tree_util.tree_map(lambda a: (tuple(a.shape), str(a.dtype)), tree)
+
+
+def _check_tree(what: str, want, have) -> None:
+    if _tree_type(want) != _tree_type(have):
+        raise ValueError(f"{what}: the system's tree is not the configuration's: "
+                         f"{_tree_type(want)} != {_tree_type(have)}")
+
+
+def _handing(model, frozen):
+    """``model``, whose ``init`` hands out ``frozen`` once: under LoRA the
+    ``Trainer`` asks its model's ``init`` for the base that it freezes.  A
+    second call is an error: the program would then hold a base that is not
+    the seed's."""
+    handed = [frozen]
+
+    class Handed(type(model)):
+        def init(self, key):
+            if not handed:
+                raise RuntimeError(f"{model.name}: the program asked its model for the "
+                                   f"base a second time; the benchmark hands it out once")
+            return handed.pop()
+
+    return Handed(model.name, model.defs, model.apply, model.num_classes,
+                  model.input_shape, model.is_sequence), handed
+
+
+# weak references to the leaves of the base last handed to the program
+_handed_leaves: List[Any] = []
 
 
 def build(config: Dict[str, Any], traffic: Dict[str, Any], fed: tr.Federation,
-          weights: Any, seed: int):
-    """A ``Trainer`` for the cell, holding ``weights`` as its global model."""
+          weights: Any, seed: int, frozen: Any = None):
+    """A ``Trainer`` for the cell, holding ``weights`` as its global model
+    (the tree it trains) and ``frozen``, where given, as the base it
+    freezes.  Keeps only weak references to ``frozen``, for ``release``."""
     from repro.core.config import Config
     from repro.core.rounds import Trainer
     from repro.core.server import Server
@@ -84,19 +135,46 @@ def build(config: Dict[str, Any], traffic: Dict[str, Any], fed: tr.Federation,
 
     cfg = Config.make(program_config(config, traffic, seed))
     model = get_model(cfg.model)
-    want = jax.tree_util.tree_map(lambda a: (a.shape, str(a.dtype)),
-                                  jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    have = jax.tree_util.tree_map(lambda a: (a.shape, str(a.dtype)), weights)
-    if want != have:
-        raise ValueError(f"{cfg.model}: the system's parameters are not the "
-                         f"configuration's: {want} != {have}")
+    unused = []
+    if frozen is not None:
+        _check_tree(f"{cfg.model} frozen base",
+                    jax.eval_shape(model.init, jax.random.PRNGKey(0)), frozen)
+        _handed_leaves[:] = [weakref.ref(a) for a in jax.tree_util.tree_leaves(frozen)]
+        model, unused = _handing(model, frozen)
+        del frozen
     x0, y0 = fed.data(0)
     fed_data = FederatedDataset(clients=LazyClients(fed), test=ClientData(x0[:1], y0[:1]),
                                 num_classes=fed.num_classes)
     server = CohortServer(model, cfg, fed_data.test)
     trainer = Trainer(cfg, model, fed_data, server=server)
+    if unused:
+        raise ValueError(f"{cfg.model}: the configuration has a frozen tree, "
+                         f"and the program froze none")
+    _check_tree(f"{trainer.model.name} parameters",
+                jax.eval_shape(trainer.model.init, jax.random.PRNGKey(0)), weights)
     trainer.server.params = weights
     return trainer
+
+
+def release() -> int:
+    """Once the system is freed, drop what the program and JAX cache (the
+    program's ``lru_cache``d round programs and the models, and a frozen
+    base, that they close over; JAX's compiled programs), so that the
+    reference holds the only base on the chip.  Leaves of the handed base
+    that something of the program still holds are deleted from the
+    device; returns how many were."""
+    for name, module in list(sys.modules.items()):
+        if name == "repro" or name.startswith("repro."):
+            for fn in list(vars(module).values()):
+                if isinstance(fn, functools._lru_cache_wrapper):
+                    fn.cache_clear()
+    jax.clear_caches()
+    gc.collect()
+    kept = [a for a in (r() for r in _handed_leaves) if a is not None]
+    for a in kept:
+        a.delete()
+    _handed_leaves.clear()
+    return len(kept)
 
 
 def data_pool_inserts(trainer) -> float:

@@ -6,21 +6,25 @@ is drawn.  The same seed gives the same clients, data and cohorts; another
 seed gives the same sizes in another draw.  Nothing here imports the
 program: the program and the reference are both fed from this module.
 
-Data are class prototypes plus a per-client shift plus noise, so that the
-clients differ as writers do (``client_shift`` > 0) or are IID (0).  The
-noise of each sample is a row of a bank drawn once per seed: a client's
-data then costs the host a gather and not a fresh normal draw, as a
-dataset held in memory would, so the benchmark's own generator stays a
-small part of a round that meets mostly new clients.
+``data.kind`` names a data kind, ``data/<kind>.py`` beside ``lib/``
+(``prototypes`` where it is absent): its ``make(traffic, config, seed)``
+gives ``num_classes`` and ``client(i, n)``, client ``i``'s ``n`` samples.
+``samples_per_client`` is a number, or a distribution of client sizes
+(:func:`client_sizes`).
 """
 from __future__ import annotations
 
+import re
+from statistics import NormalDist
 from typing import Any, Dict, List, Sequence
 
 import numpy as np
 
+from spec import BENCH_DIR, SpecError, load_module
+
 STREAM_PROTOS, STREAM_CLIENT, STREAM_COHORT, STREAM_WEIGHTS = 1, 2, 3, 4
-NOISE_ROWS = 4096
+STREAM_FROZEN, STREAM_SIZES = 5, 6
+DATA_DIR = BENCH_DIR / "data"
 
 
 def rng(seed: int, *stream: int) -> np.random.Generator:
@@ -33,44 +37,58 @@ def client_id(i: int) -> str:
     return f"w{i:05d}"
 
 
+def data_kind(name: str):
+    """The module of data kind ``name``."""
+    if not re.fullmatch(r"[A-Za-z0-9_]+", name):
+        raise SpecError(f"no data kind {name!r}")
+    return load_module(DATA_DIR / f"{name}.py")
+
+
+def client_sizes(spec: Any, population: int, seed: int) -> np.ndarray:
+    """Every client's sample count.  A number gives every client that
+    many.  ``{"lognormal": {"mean": m, "sigma": s}, "min": a, "max": b}``
+    gives the population the lognormal's quantiles at ``(k + 0.5) /
+    population`` (``m`` and ``s`` of the logarithm), rounded and held to
+    ``[a, b]``, dealt to the clients by a permutation of a stream of their
+    own: every seed then has the same set of sizes in another order, so
+    the same work."""
+    if not isinstance(spec, dict):
+        return np.full(population, int(spec), np.int64)
+    if set(spec) != {"lognormal", "min", "max"}:
+        raise SpecError(f"samples_per_client: unknown distribution {sorted(spec)}")
+    ln = spec["lognormal"]
+    dist = NormalDist(float(ln["mean"]), float(ln["sigma"]))
+    logs = np.asarray([dist.inv_cdf((k + 0.5) / population) for k in range(population)])
+    sizes = np.clip(np.rint(np.exp(logs)), int(spec["min"]), int(spec["max"])).astype(np.int64)
+    return sizes[rng(seed, STREAM_SIZES).permutation(population)]
+
+
 class Federation:
     """Clients, their data and the cohort of every round, from one seed."""
 
     def __init__(self, traffic: Dict[str, Any], config: Dict[str, Any], seed: int):
-        self.t = traffic
         self.seed = int(seed)
-        self.input_shape = tuple(config["input_shape"])
-        self.num_classes = int(config["num_classes"])
         self.population = int(traffic["population"])
         if self.population > 99999:
             raise ValueError("client ids hold at most 99999 clients")
         self.cohort = int(traffic["clients_per_round"])
         self.batch_size = int(traffic["batch_size"])
         self.local_epochs = int(traffic["local_epochs"])
-        d = int(np.prod(self.input_shape))
-        g = rng(self.seed, STREAM_PROTOS)
-        self._protos = g.standard_normal((self.num_classes, d), dtype=np.float32)
-        self._bank = g.standard_normal((NOISE_ROWS, d), dtype=np.float32)
-        self._bank *= np.float32(traffic["data"]["noise"])
-        self._shift = float(traffic["data"]["client_shift"])
+        self._data = data_kind(traffic["data"].get("kind", "prototypes")).make(
+            traffic, config, self.seed)
+        self.num_classes = self._data.num_classes
         self._order = rng(self.seed, STREAM_COHORT).permutation(self.population)
+        self._sizes = client_sizes(traffic["samples_per_client"], self.population, self.seed)
 
     def size(self, i: int) -> int:
-        return int(self.t["samples_per_client"])
+        return int(self._sizes[i])
+
+    def max_size(self) -> int:
+        return int(self._sizes.max())
 
     def data(self, i: int):
-        """Client ``i``'s ``(x, y)``: float32 inputs of the configuration's
-        shape, int32 labels."""
-        n = self.size(i)
-        g = rng(self.seed, STREAM_CLIENT, i)
-        y = g.integers(0, self.num_classes, size=n).astype(np.int32)
-        x = self._bank[g.integers(0, NOISE_ROWS, size=n)]
-        x += self._protos[y]
-        if self._shift:
-            x += np.float32(self._shift) * g.standard_normal(x.shape[1], dtype=np.float32)
-        x -= x.mean()
-        x /= x.std() + np.float32(1e-6)
-        return x.reshape((n,) + self.input_shape), y
+        """Client ``i``'s ``(x, y)``, as its data kind draws them."""
+        return self._data.client(i, self.size(i))
 
     def cohort_of(self, round_id: int) -> List[int]:
         """The clients of round ``round_id``: the next ``clients_per_round``
